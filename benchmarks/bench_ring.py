@@ -10,17 +10,21 @@ CPU the win is the removed `[r, q, m]` buffer + full-buffer `psum` +
 `take`; on TPU/GPU the transfer itself also hides —
 `launch/xla_flags.py`).
 
-Each (r_shards) cell runs in a SUBPROCESS with
+Off the CPU each (r_shards) cell runs IN-PROCESS over the first r real
+devices (r up to the device count): a chip belongs to one process, so
+a child started after this process touched JAX could not reach it.  On
+a CPU host each cell runs in a SUBPROCESS with
 ``--xla_force_host_platform_device_count=<r>`` (XLA reads the flag once
 at backend init, so the parent process cannot host the multi-device
-mesh itself).  The child pre-stages padded device inputs once and times
-the COMPILED sweep program (`hist_program`) with `block_until_ready` —
+mesh itself).  Either way `_time_schedules` pre-stages padded device
+inputs once and times the COMPILED sweep program (`hist_program`) with
+`block_until_ready` —
 the schedule is the thing under test, and the engine entry point's
 per-call host glue (padding, `device_put`, readback; measured by the
 xjoin suite) would bury the tens-of-microseconds schedule delta.  The
 two schedules are timed in INTERLEAVED rounds so machine drift cancels
-instead of biasing whichever ran second, and the child asserts their
-counts bit-identical before timing.
+instead of biasing whichever ran second, and their counts are asserted
+bit-identical before timing.
 
   ``ring/range_count-{overlap|serial}-r{r}`` -> us/query, with the
   overlap rows' derived column carrying ``speedup_vs_serial`` — the
@@ -46,53 +50,63 @@ R_SHARD_COUNTS = (1, 2)
 NR, NQ, D, M = 1024, 512, 32, 16
 WARM, ROUNDS = 5, 50
 
-#: child script: stage padded device inputs once per schedule, then time
-#: the compiled hist_program in interleaved rounds; prints
-#: ``RING_ROW,<schedule>,<ms>`` lines (BEST of the timing rounds —
-#: scheduler interference on a shared host only ever adds time, so the
-#: one-sided noise makes min the faithful cost of the compiled schedule)
+def _time_schedules(r: int) -> dict[str, float]:
+    """{schedule: total_ms} of the compiled ring sweep on an
+    `(r, data=1)` join mesh over the first r local devices: BEST of the
+    timing rounds — scheduler interference on a shared host only ever
+    adds time, so the one-sided noise makes min the faithful cost of the
+    compiled schedule."""
+    import time
+
+    import jax.numpy as jnp
+    import numpy as np
+
+    import repro.core.engine as em
+    from repro.core.engine import JoinEngine
+    from repro.core.topology import RingSharded
+    from repro.launch.mesh import make_join_mesh
+
+    rng = np.random.default_rng(0)
+
+    def unit(n):
+        x = rng.normal(size=(n, D)).astype(np.float32)
+        return x / np.linalg.norm(x, axis=1, keepdims=True)
+
+    R, Q = unit(NR), unit(NQ)
+    eps = np.linspace(0.3, 1.2, M).astype(np.float32)
+    mesh = make_join_mesh(data=1, r=r)
+    runs, base = {}, None
+    for schedule, overlap in (("overlap", True), ("serial", False)):
+        eng = JoinEngine(R, "cosine", backend="jnp", mesh=mesh,
+                         topology=RingSharded(overlap=overlap))
+        got = np.asarray(eng.range_count_hist(Q, eps))
+        if base is None:
+            base = got
+        else:
+            np.testing.assert_array_equal(got, base)
+        prog = em._hist_program(eng.mesh, eng.data_axis, eng.backend,
+                                eng.metric, eng.block_q, eng.block_r,
+                                eng.eps_chunk, eng.nr, eng.topology)
+        qdev = eng._put_q(eng._pad_q(Q))
+        epdev = jnp.asarray(eng._pad_eps(eps))
+        runs[schedule] = (prog, qdev, eng._Rdev, epdev, eng._nrv_dev)
+    samples = {k: [] for k in runs}
+    for _ in range(WARM + ROUNDS):
+        for schedule, (prog, *args) in runs.items():
+            t0 = time.perf_counter()
+            prog(*args).block_until_ready()
+            samples[schedule].append(time.perf_counter() - t0)
+    return {k: float(np.min(np.array(ts[WARM:]))) * 1e3
+            for k, ts in samples.items()}
+
+
+#: child script (CPU host): force r host devices, then time in the
+#: child; prints ``RING_ROW,<schedule>,<ms>`` lines
 _CHILD = """
-import os
 from repro.launch.xla_flags import apply_xla_flags, host_device_count_flag
 apply_xla_flags(host_device_count_flag({r}))
-import time
-import numpy as np
-import jax.numpy as jnp
-import repro.core.engine as em
-from repro.core.engine import JoinEngine
-from repro.core.topology import RingSharded
-from repro.launch.mesh import make_join_mesh
-
-rng = np.random.default_rng(0)
-def unit(n):
-    x = rng.normal(size=(n, {d})).astype(np.float32)
-    return x / np.linalg.norm(x, axis=1, keepdims=True)
-R, Q = unit({nr}), unit({nq})
-eps = np.linspace(0.3, 1.2, {m}).astype(np.float32)
-mesh = make_join_mesh(data=1, r={r})
-runs, base = {{}}, None
-for schedule, overlap in (("overlap", True), ("serial", False)):
-    eng = JoinEngine(R, "cosine", backend="jnp", mesh=mesh,
-                     topology=RingSharded(overlap=overlap))
-    got = np.asarray(eng.range_count_hist(Q, eps))
-    if base is None:
-        base = got
-    else:
-        np.testing.assert_array_equal(got, base)
-    prog = em._hist_program(eng.mesh, eng.data_axis, eng.backend,
-                            eng.metric, eng.block_q, eng.block_r,
-                            eng.eps_chunk, eng.nr, eng.topology)
-    qdev = eng._put_q(eng._pad_q(Q))
-    epdev = jnp.asarray(eng._pad_eps(eps))
-    runs[schedule] = (prog, qdev, eng._Rdev, epdev, eng._nrv_dev)
-samples = {{k: [] for k in runs}}
-for rep in range({warm} + {rounds}):
-    for schedule, (prog, *args) in runs.items():
-        t0 = time.perf_counter()
-        prog(*args).block_until_ready()
-        samples[schedule].append(time.perf_counter() - t0)
-for schedule, ts in samples.items():
-    ms = float(np.min(np.array(ts[{warm}:]))) * 1e3
+from benchmarks.bench_ring import _time_schedules
+for schedule, ms in _time_schedules({r}).items():
     print(f"RING_ROW,{{schedule}},{{ms:.4f}}", flush=True)
 """
 
@@ -101,11 +115,9 @@ def _child_rows(r: int) -> dict[str, float]:
     """{schedule: total_ms} from one forced-`r`-device subprocess."""
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
-    code = _CHILD.format(r=r, nr=NR, nq=NQ, d=D, m=M, warm=WARM,
-                         rounds=ROUNDS)
-    out = subprocess.run([sys.executable, "-c", code], env=env,
-                         cwd=REPO_ROOT, capture_output=True, text=True,
-                         timeout=900)
+    out = subprocess.run([sys.executable, "-c", _CHILD.format(r=r)],
+                         env=env, cwd=REPO_ROOT, capture_output=True,
+                         text=True, timeout=900)
     if out.returncode != 0:
         raise RuntimeError(f"bench_ring child (r={r}) failed:\n"
                            + out.stderr[-3000:])
@@ -121,9 +133,13 @@ def _child_rows(r: int) -> dict[str, float]:
 
 
 def run() -> list:
+    import jax
+    in_process = jax.default_backend() != "cpu"
     rows = []
     for r in R_SHARD_COUNTS:
-        ms = _child_rows(r)
+        if in_process and r > len(jax.devices()):
+            continue
+        ms = _time_schedules(r) if in_process else _child_rows(r)
         speedup = ms["serial"] / max(ms["overlap"], 1e-9)
         for schedule in ("overlap", "serial"):
             derived = (f"speedup_vs_serial={speedup:.3f}"

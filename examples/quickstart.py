@@ -20,12 +20,12 @@ t0 = time.time()
 plan = (JoinPlan(R, spec.metric)
         .filter("xling", tau=TAU, xdt="fpr", estimator="nn", epochs=12)
         .search("naive")
-        .on(backend="jnp", cache_key=("quickstart", N))
+        .on(backend="auto", cache_key=("quickstart", N))
         .build())
 print(f"offline build: {time.time()-t0:.1f}s "
       f"(ground-truth targets + ATCS + estimator training)")
 
-naive = make_join("naive", R, spec.metric, backend="jnp")
+naive = make_join("naive", R, spec.metric, backend="auto")
 naive.query_counts(S, EPS)                       # warm the jit
 t0 = time.time(); truth = naive.query_counts(S, EPS); t_naive = time.time() - t0
 

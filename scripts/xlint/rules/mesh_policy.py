@@ -1,8 +1,9 @@
-"""mesh-policy rule (DESIGN.md §7): one mesh constructor, version-compat.
+"""mesh-policy rule (DESIGN.md §7): one mesh constructor.
 
-JAX 0.4.37 lacks `jax.sharding.AxisType`; every mesh in the repo must be
-built through `launch/mesh.py::make_mesh`, which feature-detects the
-enum.  This rule rejects, everywhere EXCEPT that module:
+Every mesh in the repo must be built through `launch/mesh.py::make_mesh`,
+which pins each axis to `AxisType.Auto` so sharding propagation does not
+depend on the default of the installed JAX.  This rule rejects,
+everywhere EXCEPT that module:
 
   * `jax.sharding.Mesh(...)` / bare imported `Mesh(...)` constructor calls
   * `jax.make_mesh(...)` calls
@@ -60,7 +61,7 @@ class MeshPolicyRule(Rule):
                 out.append(self.violation(
                     lf, node.lineno,
                     "jax.sharding.AxisType referenced directly — "
-                    "launch/mesh.py::make_mesh owns version compat"))
+                    "launch/mesh.py::make_mesh owns the axis types"))
             elif isinstance(node, ast.Call):
                 name = _dotted(node.func)
                 if name.endswith("sharding.Mesh") or name == "Mesh":
@@ -78,8 +79,7 @@ class MeshPolicyRule(Rule):
                         out.append(self.violation(
                             lf, node.lineno,
                             "axis_types= passed directly — only "
-                            "launch/mesh.py::make_mesh may feature-detect "
-                            "it"))
+                            "launch/mesh.py::make_mesh sets it"))
             elif isinstance(node, ast.ImportFrom):
                 if node.module and node.module.startswith("jax.sharding"):
                     for alias in node.names:

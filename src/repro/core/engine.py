@@ -78,7 +78,7 @@ from typing import Callable, Iterable, Iterator, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding
+from jax.sharding import NamedSharding, SingleDeviceSharding
 
 from repro.core.topology import (Topology, _data_size, _zero_row_distance,
                                  resolve_topology)
@@ -103,6 +103,17 @@ def _bucket_size(n: int, block: int) -> int:
             if c >= n:
                 return c
     return b
+
+
+def _put_row_major(x: np.ndarray, sharding) -> jax.Array:
+    """`device_put` with the row-major layout pinned.  On TPU a 2-D f32
+    array whose minor dim is not a multiple of 128 defaults to a
+    column-major layout, and every program that tiles or gathers rows of
+    it (the range_count kernel, candidate verification) would first copy
+    it whole into row-major; a jitted program compiles for the layout of
+    a committed argument, so pinning it at upload removes those copies."""
+    from jax.experimental.layout import Format, Layout
+    return jax.device_put(x, Format(Layout(tuple(range(x.ndim))), sharding))
 
 
 def _pad_rows_np(x: np.ndarray, n: int) -> np.ndarray:
@@ -378,10 +389,7 @@ def _start_host_copy(arr) -> None:
     `_allowed_transfer("result")`), so it runs inside an explicit
     device→host allow window of its own — one readback, two phases."""
     with jax.transfer_guard_device_to_host("allow"):
-        try:
-            arr.copy_to_host_async()
-        except AttributeError:
-            pass                            # backend without async copies
+        arr.copy_to_host_async()
 
 
 class _WorldView:
@@ -649,11 +657,12 @@ class JoinEngine:
                                             self.mesh)
         if self.mesh is not None:
             r_sharding = NamedSharding(self.mesh, self.topology.r_spec())
-            self._Rdev = jax.device_put(Rp, r_sharding)
+            self._Rdev = _put_row_major(Rp, r_sharding)
             self._nrv_dev = None if nrv is None else jax.device_put(
                 nrv, r_sharding)
         else:
-            self._Rdev = jnp.asarray(Rp)
+            self._Rdev = _put_row_major(
+                Rp, SingleDeviceSharding(jax.devices()[0]))
             self._nrv_dev = None if nrv is None else jnp.asarray(nrv)
 
     @property

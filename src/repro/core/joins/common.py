@@ -75,7 +75,8 @@ def assign_nearest(X: np.ndarray, centroids: np.ndarray, block: int = 4096) -> n
 
 def build_capacity_table(assignments: np.ndarray, n_buckets: int,
                          cap: int | None = None) -> np.ndarray:
-    """Dense [n_buckets, cap] member table (-1 padded) from bucket ids."""
+    """Dense [n_buckets, cap] member table (-1 padded) from bucket ids:
+    each bucket keeps its first `cap` members in row order."""
     order = np.argsort(assignments, kind="stable")
     sorted_b = assignments[order]
     counts = np.bincount(assignments, minlength=n_buckets)
@@ -83,9 +84,9 @@ def build_capacity_table(assignments: np.ndarray, n_buckets: int,
         cap = max(int(counts.max()), 1)
     table = np.full((n_buckets, cap), -1, np.int32)
     starts = np.concatenate([[0], np.cumsum(counts)[:-1]])
-    for b in np.nonzero(counts)[0]:
-        c = min(counts[b], cap)
-        table[b, :c] = order[starts[b]:starts[b] + c]
+    slot = np.arange(len(order)) - starts[sorted_b]     # rank in its bucket
+    keep = slot < cap
+    table[sorted_b[keep], slot[keep]] = order[keep]
     return table
 
 
@@ -101,7 +102,8 @@ def _verify_block_impl(R, q, cand, eps, *, metric, tomb=None):
     if tomb is not None:
         valid &= tomb[jnp.maximum(cand_sorted, 0)] == 0
     x = R[jnp.maximum(cand_sorted, 0)]                   # [bq, C, d]
-    dots = jnp.einsum("qcd,qd->qc", x.astype(jnp.float32), q.astype(jnp.float32))
+    dots = jnp.einsum("qcd,qd->qc", x.astype(jnp.float32), q.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
     if metric == "cosine":
         d = 1.0 - dots
     else:
@@ -145,7 +147,8 @@ def _verify_block_live(R, q, cand, eps, *, metric, tomb=None,
         v_sl = jax.lax.dynamic_slice_in_dim(valid, i * chunk, chunk, 1)
         x = R[jnp.maximum(c_sl, 0)]                  # [bq, chunk, d]
         dots = jnp.einsum("qcd,qd->qc", x.astype(jnp.float32),
-                          q.astype(jnp.float32))
+                          q.astype(jnp.float32),
+                          precision=jax.lax.Precision.HIGHEST)
         if metric == "cosine":
             d = 1.0 - dots
         else:

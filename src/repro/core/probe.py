@@ -64,6 +64,7 @@ from repro.core.joins.common import (_verify_block_impl, _verify_blocks,
                                      localized_shard_verify)
 from repro.core.topology import _data_size, _shard_mapped
 from repro.kernels import ops
+from repro.kernels.lsh_gather import bucket_minor
 
 # ====================================================== shared LSH math
 # Bucket combination runs in int32 with two's-complement wraparound on
@@ -126,22 +127,38 @@ def _lsh_probe_fn(X, proj, bias, salt32, *, metric, W, n_probes, n_buckets):
                            n_buckets=n_buckets)
 
 
+#: rows per device call of the host hashing entries: an index of millions
+#: of rows is hashed in slices, so its [n, l, k] codes never sit on the
+#: device at once
+_HASH_CHUNK = 1 << 18
+
+
+def _by_rows(fn, X) -> np.ndarray:
+    """fn over row slices of X (at most `_HASH_CHUNK` rows per device
+    call), concatenated on host; fn is row-wise."""
+    n = len(X)
+    if n <= _HASH_CHUNK:
+        return np.asarray(fn(X))
+    return np.concatenate([np.asarray(fn(X[i:i + _HASH_CHUNK]))
+                           for i in range(0, n, _HASH_CHUNK)])
+
+
 def lsh_hash_codes(X, proj, bias, *, metric: str, W: float) -> np.ndarray:
     """Host entry: int32 [n, l, k] codes via the shared device math —
     the single implementation behind table build, host probing, and the
     device probe programs (bit parity by construction)."""
-    return np.asarray(_lsh_codes_fn(
-        jnp.asarray(X, jnp.float32), jnp.asarray(proj, jnp.float32),
-        jnp.asarray(bias, jnp.float32), metric=metric, W=float(W)))
+    proj, bias = jnp.asarray(proj, jnp.float32), jnp.asarray(bias, jnp.float32)
+    return _by_rows(lambda x: _lsh_codes_fn(
+        jnp.asarray(x, jnp.float32), proj, bias, metric=metric, W=float(W)),
+        X)
 
 
 def lsh_bucket_ids(codes, salt, n_buckets: int) -> np.ndarray:
     """Host entry: int32 [n, l] bucket ids for table build (same int32
     wraparound combine as probing — build and probe can never skew)."""
-    return np.asarray(_lsh_combine_fn(
-        jnp.asarray(codes, jnp.int32),
-        jnp.asarray(np.asarray(salt, np.int64).astype(np.int32)),
-        n_buckets=int(n_buckets)))
+    salt32 = jnp.asarray(np.asarray(salt, np.int64).astype(np.int32))
+    return _by_rows(lambda c: _lsh_combine_fn(
+        jnp.asarray(c, jnp.int32), salt32, n_buckets=int(n_buckets)), codes)
 
 
 def _bucket_rows(X: np.ndarray) -> np.ndarray:
@@ -344,6 +361,16 @@ def ivfpq_candidates(Q, centroids, lists, codes, codebooks, *, n_probe: int,
 
 
 # ============================================= compiled device programs
+def _whole_on_each_device(fn, mesh, n_args: int):
+    """`fn` run whole on every device of `mesh`, inputs and output
+    replicated.  A program holding a Pallas kernel must be mapped this
+    way on a multi-device mesh: the compiler cannot partition a Mosaic
+    kernel, even over replicated operands."""
+    if mesh is None or mesh.size == 1:
+        return fn
+    return _shard_mapped(fn, mesh, in_specs=(P(),) * n_args, out_specs=P())
+
+
 @register_program_cache
 @functools.lru_cache(maxsize=128)
 def _gather_program(mesh, data_axis):
@@ -366,21 +393,21 @@ def _gather_program(mesh, data_axis):
 
 @register_program_cache
 @functools.lru_cache(maxsize=128)
-def _lsh_probe_program(metric, W, n_probes, n_buckets, backend="jnp"):
+def _lsh_probe_program(mesh, metric, W, n_probes, n_buckets, backend="jnp"):
     """Compiled replicated LSH probe `(qpos, proj, bias, salt, tables) ->
     cand [q, l*p*cap]` — tables are runtime args, so every engine with
     this geometry shares one executable.  The member-table gather +
     multiprobe dedup runs through `ops.lsh_bucket_gather`
     (kernels/lsh_gather.py): the fused Pallas kernel under
     backend="pallas", the bit-identical direct-gather formulation
-    otherwise."""
+    otherwise; every device of `mesh` probes the whole batch."""
     def run(qpos, proj, bias, salt, tables):
         codes = _lsh_codes(qpos, proj, bias, metric=metric, W=W)
         pb = _lsh_multiprobe(codes, salt, metric=metric, n_probes=n_probes,
                              n_buckets=n_buckets)
         return ops.lsh_bucket_gather(tables, pb, backend=backend)
 
-    return jax.jit(run)
+    return jax.jit(_whole_on_each_device(run, mesh, 5))
 
 
 @register_program_cache
@@ -417,7 +444,8 @@ def _expand_pb(pb, expand):
 
 @register_program_cache
 @functools.lru_cache(maxsize=128)
-def _lsh_expand_probe_program(metric, W, n_probes, n_buckets, backend="jnp"):
+def _lsh_expand_probe_program(mesh, metric, W, n_probes, n_buckets,
+                              backend="jnp"):
     """`_lsh_probe_program` with skew-aware re-bucketing (DESIGN.md
     §16): the multiprobe schedule is unchanged (bucket domain [0, B)),
     then every probed bucket expands to its child buckets through the
@@ -431,7 +459,7 @@ def _lsh_expand_probe_program(metric, W, n_probes, n_buckets, backend="jnp"):
         return ops.lsh_bucket_gather(tables, _expand_pb(pb, expand),
                                      backend=backend)
 
-    return jax.jit(run)
+    return jax.jit(_whole_on_each_device(run, mesh, 6))
 
 
 @register_program_cache
@@ -549,19 +577,24 @@ def _shard_lsh_tables(tables: np.ndarray, shards: int,
     rebuild from scratch), the union over shards equals the global
     table bit-for-bit — per-shard probing stays candidate-identical to
     the replicated probe, and per-device table bytes drop by roughly
-    the shard count (cap_s ≈ cap / shards on balanced data)."""
-    vals = tables.astype(np.int64)
-    big = np.int64(1) << 40
-    per, caps = [], []
+    the shard count (cap_s ≈ cap / shards on balanced data).  Works in
+    int32 one shard at a time: besides the input and the output, only
+    one shard's masked copy of the table exists at once."""
+    tables = np.asarray(tables, np.int32)
+
+    def in_shard(s):
+        return (tables >= s * rows) & (tables < (s + 1) * rows)
+
+    cap_s = max(max(int(in_shard(s).sum(axis=-1).max())
+                    for s in range(shards)), 1)
+    empty = np.iinfo(np.int32).max          # sorts after every row id
+    out = np.empty((shards,) + tables.shape[:-1] + (cap_s,), np.int32)
     for s in range(shards):
-        lo, hi = s * rows, (s + 1) * rows
-        m = (vals >= lo) & (vals < hi)
-        per.append(np.sort(np.where(m, vals, big), axis=-1))
-        caps.append(int(m.sum(axis=-1).max()))
-    cap_s = max(max(caps), 1)
-    out = np.stack([p[..., :cap_s] for p in per])
-    out[out >= big] = -1
-    return out.astype(np.int32)
+        part = np.where(in_shard(s), tables, empty)
+        part.sort(axis=-1)
+        part = part[..., :cap_s]
+        out[s] = np.where(part == empty, -1, part)
+    return out
 
 
 # ================================================ specs + placed probes
@@ -622,7 +655,8 @@ def _device_put(arr, mesh, spec=P()):
 
 class LSHProbe:
     """Device-probe spec for `LSHJoin` (DESIGN.md §11): projection /
-    bias / salt / member tables uploaded once; under the ring topology
+    bias / salt / member tables uploaded once (the tables bucket-minor,
+    `lsh_gather.bucket_minor`); under the ring topology
     the member tables are row-partitioned over `r`
     (`_shard_lsh_tables`) so probing AND verification stay local to
     each R shard."""
@@ -650,8 +684,8 @@ class LSHProbe:
             small = small + (_device_put(np.asarray(expand, np.int32),
                                          mesh),)
         if shards > 1:
-            tabs = _shard_lsh_tables(j.tables, shards,
-                                     engine.nr_padded // shards)
+            tabs = bucket_minor(_shard_lsh_tables(
+                j.tables, shards, engine.nr_padded // shards))
             tables = _device_put(tabs, mesh, engine.topology.probe_spec())
             if expand is None:
                 prog = _lsh_ring_probe_program(
@@ -664,19 +698,20 @@ class LSHProbe:
             table_bytes = (tabs.nbytes // shards + j.proj.nbytes
                            + j.bias.nbytes + salt32.nbytes)
             cand_width = (shards * tabs.shape[1] * j.n_probes * fanout
-                          * tabs.shape[3])
+                          * tabs.shape[2])
             cand_sharded = True
         else:
-            tables = _device_put(np.asarray(j.tables, np.int32), mesh)
+            tab = bucket_minor(j.tables)
+            tables = _device_put(tab, mesh)
             if expand is None:
-                prog = _lsh_probe_program(j.metric, float(j.W),
+                prog = _lsh_probe_program(mesh, j.metric, float(j.W),
                                           int(j.n_probes),
                                           int(j.n_buckets), engine.backend)
             else:
                 prog = _lsh_expand_probe_program(
-                    j.metric, float(j.W), int(j.n_probes),
+                    mesh, j.metric, float(j.W), int(j.n_probes),
                     int(j.n_buckets), engine.backend)
-            table_bytes = (j.tables.nbytes + j.proj.nbytes + j.bias.nbytes
+            table_bytes = (tab.nbytes + j.proj.nbytes + j.bias.nbytes
                            + salt32.nbytes)
             cand_width = j.l * j.n_probes * fanout * j.tables.shape[2]
             cand_sharded = False
@@ -712,10 +747,12 @@ class IVFPQProbe:
                  _device_put(j.codes, mesh),
                  _device_put(j.codebooks, mesh))
 
-        def prog(qpos, centroids, lists, codes, codebooks):
+        def run(qpos, centroids, lists, codes, codebooks):
             return _ivfpq_probe_fn(qpos, centroids, lists, codes, codebooks,
                                    n_probe=int(j.n_probe), n_cand=n_cand,
                                    backend=engine.backend)
+
+        prog = jax.jit(_whole_on_each_device(run, mesh, 5))
 
         table_bytes = (j.centroids.nbytes + j.lists.nbytes + j.codes.nbytes
                        + j.codebooks.nbytes)

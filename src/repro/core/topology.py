@@ -56,21 +56,19 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-try:                                    # moved to the stable namespace in
-    from jax import shard_map           # newer JAX; experimental on 0.4.x
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.kernels import ops, ref
 from repro.kernels.range_count import range_count_hist_pallas
 
 
 def _shard_mapped(f, mesh, in_specs, out_specs):
-    try:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    except TypeError:                   # newer API dropped check_rep
-        return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    """`jax.shard_map` with the varying-manual-axes check off: the ring
+    programs carry per-shard values (rotating query blocks, partial
+    counts) through Python-unrolled loops and scans whose carries start
+    replicated, which the check would reject as a type mismatch."""
+    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                     check_vma=False)
 
 
 def _data_size(mesh, data_axis: str) -> int:
@@ -95,12 +93,10 @@ def _per_shard_hist(backend, metric, block_q, block_r, eps_chunk, nr_valid):
     every row" (the ring topology masks via the zero-row correction
     instead, because its per-shard valid counts are traced values)."""
     if backend == "pallas":
-        interpret = jax.default_backend() != "tpu"
-
         def shard_fn(q, r, eps):
             return range_count_hist_pallas(
                 q, r, eps, metric=metric, nr_valid=nr_valid, block_q=block_q,
-                block_r=block_r, eps_chunk=eps_chunk, interpret=interpret)
+                block_r=block_r, eps_chunk=eps_chunk)
     elif backend == "ref":
         def shard_fn(q, r, eps):
             return ref.range_count_hist(q, r, eps, metric)
@@ -228,7 +224,7 @@ class Topology:
         `sweep(qpos, r, eps1, nrv, capacity) -> int32 [capacity]` hook:
         gather the positives into the bucketed static shape, sweep them,
         and scatter the counts back (padding lanes all add 0 onto row 0).
-        One place owns the compaction/donation conventions so the
+        One place owns the compaction conventions so the
         topologies cannot diverge."""
 
         def prog(q, pos, n_pos, r, eps, nrv, *, capacity: int):
@@ -240,11 +236,7 @@ class Topology:
             contrib = jnp.where(valid, found, 0).astype(jnp.int32)
             return jnp.zeros((q.shape[0],), jnp.int32).at[idx].add(contrib)
 
-        # the padded query buffer is dead after this program — donate it on
-        # TPU so the compact output can reuse its HBM (CPU donation warns)
-        donate = (0,) if jax.default_backend() == "tpu" else ()
-        return jax.jit(prog, static_argnames=("capacity",),
-                       donate_argnums=donate)
+        return jax.jit(prog, static_argnames=("capacity",))
 
 
 # ================================================================ replicated
@@ -284,8 +276,7 @@ class Replicated(Topology):
             shard_fn = _shard_mapped(shard_fn, mesh,
                                      in_specs=(P(data_axis), P(), P()),
                                      out_specs=P(data_axis))
-        jitted = jax.jit(shard_fn)
-        return lambda q, r, eps, nrv=None: jitted(q, r, eps)
+        return jax.jit(lambda q, r, eps, nrv=None: shard_fn(q, r, eps))
 
     def compact_program(self, mesh, data_axis, backend, metric, block_q,
                         block_r, nr_valid):
@@ -300,12 +291,8 @@ class Replicated(Topology):
                     qpos, NamedSharding(mesh, P(data_axis)))
             if backend == "ref":
                 return ref.range_count_hist(qpos, r, eps1, metric)[:, 0]
-            if capacity > block_q and capacity % block_q == 0:
-                # large buckets get the same query tiling as the main sweep
-                # so the compare temporaries stay O(block_q * block_r)
-                return _q_blocked_hist(qpos, r, eps1, metric=metric,
-                                       block_q=block_q, block_r=block_r,
-                                       nr_valid=nr_valid)[:, 0]
+            # one eps: the compare temporary is [capacity, block_r], so the
+            # positives need no query tiling
             return ops.blocked_hist(qpos, r, eps1, metric=metric,
                                     block_r=block_r, nr_valid=nr_valid)[:, 0]
 
@@ -507,15 +494,10 @@ class RingSharded(Topology):
         def sweep(qpos, r, eps1, nrv, capacity):
             shard_data = ndata > 1 and capacity % ndata == 0
             qspec = P(data_axis) if shard_data else P()
-            rows_local = capacity // ndata if shard_data else capacity
 
             def shard_fn(qp, rs, e, nv):
                 if backend == "ref":
                     found = ref.range_count_hist(qp, rs, e, metric)
-                elif rows_local > block_q and rows_local % block_q == 0:
-                    found = _q_blocked_hist(qp, rs, e, metric=metric,
-                                            block_q=block_q, block_r=block_r,
-                                            nr_valid=rs.shape[0])
                 else:
                     found = ops.blocked_hist(qp, rs, e, metric=metric,
                                              block_r=block_r,

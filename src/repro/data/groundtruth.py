@@ -48,8 +48,12 @@ def cardinality_table(points: np.ndarray, index_set: np.ndarray,
     OTHER point nearby", matching the join semantics R x S).
     """
     if cache_key is not None:
+        # the platform is part of the key: a table swept on one device
+        # kind is never served to a run on another
+        import jax
         path = cache_path("gt-v1", cache_key, len(points), len(index_set),
-                          len(eps_grid), metric, exclude_self)
+                          len(eps_grid), metric, exclude_self,
+                          jax.default_backend())
         try:
             with np.load(path) as z:
                 return z["t"]

@@ -93,6 +93,14 @@ def _generate(spec: DatasetSpec, n: int, seed: int) -> np.ndarray:
     return x.astype(np.float32)
 
 
+def generate(name: str, n: int, seed: int = 0, sample: int = 1) -> np.ndarray:
+    """n float32 unit rows of corpus `name`, drawn afresh from `seed` with
+    no disk cache (`load_dataset` caches on top of this; corpora of
+    millions of rows are cheaper to redraw than to compress and reload).
+    sample=2 gives the disjoint second draw."""
+    return _generate(DATASETS[name], n, seed + 104729 * (sample - 1))
+
+
 def load_dataset(name: str, n: int = 20000, seed: int = 0, sample: int = 1,
                  split: bool = True):
     """Returns (R, S, spec) with |R|:|S| = 8:2, or (X, spec) if split=False.
@@ -106,7 +114,7 @@ def load_dataset(name: str, n: int = 20000, seed: int = 0, sample: int = 1,
         with np.load(path) as z:
             x = z["x"]
     except (FileNotFoundError, OSError):
-        x = _generate(spec, n, seed + 104729 * (sample - 1))
+        x = generate(name, n, seed, sample)
         np.savez_compressed(path, x=x)
     if not split:
         return x, spec

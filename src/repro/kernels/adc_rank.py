@@ -1,43 +1,46 @@
-"""Pallas TPU kernel: flash-style fused IVF-PQ ADC ranking.
+"""Pallas TPU kernel: IVF-PQ ADC scoring for the candidate ranking.
 
 The IVF-PQ device probe (core/probe.py `_ivfpq_block`) used to rank the
 candidate pool through a generic XLA chain: build the full ``[b, m,
 256]`` LUT tensor, ``transpose`` it, gather per-candidate codes with
 ``take_along_axis``, reduce over segments, then ``top_k`` — four HBM
-round-trips over intermediates larger than the inputs.  This kernel
-fuses the whole pipeline into one VMEM residency per query tile, in the
-spirit of flash attention's "never materialize the big intermediate":
+round-trips over intermediates larger than the inputs.  The kernel path
+splits the work where each side is strongest:
 
-  1. **LUT build** — per PQ segment ``mi`` the ``[Bb, 256]`` distance
-     table ``|q_mi|^2 - 2 q_mi . c + |c|^2`` is one MXU matmul against
-     the VMEM-resident codebook slice (`lut_segment`, shared verbatim
-     with the jnp path).
-  2. **code gather** — the candidates' PQ code rows stream out of the
-     VMEM-resident ``[n, m]`` code table via a ``fori_loop`` of dynamic
-     row slices (no gather primitive inside Pallas kernels).
-  3. **accumulate** — the per-segment LUT lookup is a one-hot matmul
-     over the 256 codewords (exact: products are value*1.0/value*0.0
-     and adding zeros is exact), accumulated in ascending segment order.
-  4. **streaming top-k** — ``n_cand`` rounds of masked argmin selection
-     with first-index tie-breaking, which reproduces `jax.lax.top_k`'s
-     documented tie order exactly (lower index first).
+  1. **LUT build** (XLA) — per PQ segment ``mi`` the ``[b, 256]`` table
+     ``|q_mi|^2 - 2 q_mi . c + |c|^2`` is one matmul against the codebook
+     slice (`lut_segment`, shared verbatim with the jnp path, so both
+     paths look up the same bits).
+  2. **code gather** (XLA) — the candidates' PQ code rows are gathered
+     from the ``[n, m]`` code table in HBM by an XLA gather; the table
+     itself never enters VMEM, so its size is bounded by HBM, not by the
+     kernel's scoped VMEM.
+  3. **lookup + accumulate** (kernel) — grid ``(query blocks, candidate
+     blocks)``; per query row and segment the LUT lookup is a
+     select-and-sum over the 256 codewords on the VPU (exact: one
+     selected value plus zeros), accumulated in ascending segment order
+     into the ``[b, C]`` ADC distances.  Tiling the candidate axis keeps
+     VMEM bounded for any pool width (IVF-PQ pools are ``n_probe x
+     list capacity`` wide — tens of thousands at paper scale).
+  4. **rank** (XLA) — `_rank_tail`: ``-1`` lanes to inf, `jax.lax.top_k`,
+     id gather — the same function the jnp path ends with.
 
 Bit-identity contract: the jnp path (`adc_rank_jnp`) accumulates the
-same per-segment lookups in the same ascending order and selects with
-`jax.lax.top_k`, so pallas and jnp candidate ids are bit-identical by
-construction — including inf ties from ``-1`` padding lanes and
-duplicate ids from overlapping inverted lists.  The pre-existing
-transpose+take_along_axis+top_k chain survives as `adc_rank_chain` (the
-ops-level "ref" backend and the benchmark baseline); its segment
-reduction order is whatever XLA picks for ``.sum()``, so it is
-value-identical but not guaranteed bit-identical on ties.
+same per-segment lookups of the same LUT values in the same ascending
+order and ranks with the same `_rank_tail`, so pallas and jnp candidate
+ids are bit-identical by construction — including inf ties from ``-1``
+padding lanes and duplicate ids from overlapping inverted lists.  The
+pre-existing transpose+take_along_axis+top_k chain survives as
+`adc_rank_chain` (the ops-level "ref" backend and the benchmark
+baseline); its segment reduction order is whatever XLA picks for
+``.sum()``, so it is value-identical but not guaranteed bit-identical on
+ties.
 
-VMEM budget at the default ``block_b=8`` (C = n_probe*cap candidates,
-typically <= 512; m <= 16 segments; codes n*m uint8): codebooks
-m*256*seg f32 <= 1 MB, code table <= a few MB for bench-scale n, onehot
-``[Bb, C, 256]`` f32 = 8*512*256*4 = 4 MB, LUT slice 8*256*4 = 8 KB —
-inside the ~16 MB budget.  The kernel engages when the code table fits
-VMEM (the replicated-probe regime).
+VMEM budget at the defaults (``block_b=8``, ``block_c=512``, m <= 32):
+LUT block ``[m, 256, 8]`` f32 lane-padded to 128 = 4 MB and codes
+``[m, 8, 512]`` int32 = 512 KB (each double-buffered), plus the ``[256,
+512]`` select temporaries (1 MB) — about 10 MB, independent of the
+corpus size and of the pool width.
 """
 from __future__ import annotations
 
@@ -53,9 +56,11 @@ from repro.kernels.range_count import default_interpret
 def lut_segment(q_mi: jax.Array, cb_mi: jax.Array) -> jax.Array:
     """f32 [b, 256] ADC table for ONE PQ segment: ``|q|^2 - 2 q.c +
     |c|^2`` with q_mi [b, seg], cb_mi [256, seg].  The single source of
-    truth for both the jnp path and the kernel body — identical
-    primitive sequence means identical bits."""
+    truth for both the jnp path and the kernel path — identical
+    primitive sequence means identical bits.  HIGHEST keeps the dot in
+    f32 on TPU (the default rounds both operands to bf16)."""
     dots = jax.lax.dot_general(q_mi, cb_mi, (((1,), (1,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
     return (jnp.sum(q_mi * q_mi, -1)[:, None] - 2.0 * dots
             + jnp.sum(cb_mi * cb_mi, -1)[None, :])
@@ -73,11 +78,21 @@ def adc_rank_chain(q: jax.Array, codebooks: jax.Array, cand: jax.Array,
     m, _, seg = codebooks.shape
     qseg = q.reshape(b, m, seg)
     tables = (jnp.sum(qseg * qseg, -1)[:, :, None]
-              - 2.0 * jnp.einsum("bms,mcs->bmc", qseg, codebooks)
+              - 2.0 * jnp.einsum("bms,mcs->bmc", qseg, codebooks,
+                                 precision=jax.lax.Precision.HIGHEST)
               + jnp.sum(codebooks * codebooks, -1)[None])
     code_blk = codes[jnp.maximum(cand, 0)].astype(jnp.int32)
     adc = jnp.take_along_axis(jnp.transpose(tables, (0, 2, 1)),
                               code_blk, axis=1).sum(axis=2)
+    adc = jnp.where(cand < 0, jnp.inf, adc)
+    _, top = jax.lax.top_k(-adc, n_cand)
+    return jnp.take_along_axis(cand, top, axis=1)
+
+
+def _rank_tail(adc: jax.Array, cand: jax.Array, n_cand: int) -> jax.Array:
+    """The n_cand lowest-ADC candidate ids: ``-1`` lanes rank last,
+    `jax.lax.top_k` tie order (lower lane first).  Shared by the jnp and
+    pallas paths."""
     adc = jnp.where(cand < 0, jnp.inf, adc)
     _, top = jax.lax.top_k(-adc, n_cand)
     return jnp.take_along_axis(cand, top, axis=1)
@@ -98,60 +113,34 @@ def adc_rank_jnp(q: jax.Array, codebooks: jax.Array, cand: jax.Array,
     for mi in range(m):
         lut = lut_segment(qseg[:, mi], codebooks[mi])           # [b, 256]
         adc = adc + jnp.take_along_axis(lut, code_blk[:, :, mi], axis=1)
-    adc = jnp.where(cand < 0, jnp.inf, adc)
-    _, top = jax.lax.top_k(-adc, n_cand)
-    return jnp.take_along_axis(cand, top, axis=1)
+    return _rank_tail(adc, cand, n_cand)
 
 
-def _kernel(q_ref, cb_ref, cand_ref, codes_ref, out_ref, *, n_cand: int):
-    qseg = q_ref[...].astype(jnp.float32)         # [Bb, m, seg]
-    cbs = cb_ref[...].astype(jnp.float32)         # [m, 256, seg]
-    cand = cand_ref[...]                          # [Bb, C] int32
-    codes = codes_ref[...].astype(jnp.int32)      # [n, m]
-    bb, c = cand.shape
-    m = qseg.shape[1]
-    safe = jnp.maximum(cand, 0)
+#: candidates per pool tile (clipped to the pool width, rounded up to 128)
+_BLOCK_C = 512
 
-    # (2) fused code gather: candidate rows stream out of the resident
-    # code table one dynamic row slice per (query, candidate) lane
-    def gather(t, acc):
-        bi, ci = t // c, t % c
-        row = jax.lax.dynamic_slice(codes, (safe[bi, ci], 0), (1, m))
-        return jax.lax.dynamic_update_slice(acc, row[None], (bi, ci, 0))
 
-    code_blk = jax.lax.fori_loop(0, bb * c, gather,
-                                 jnp.zeros((bb, c, m), jnp.int32))
+def _kernel(lut_ref, codes_ref, out_ref):
+    bb, c = out_ref.shape
+    code_iota = jax.lax.broadcasted_iota(jnp.int32, (256, c), 0)
+    row = jax.lax.broadcasted_iota(jnp.int32, (bb, c), 0)
 
-    # (1)+(3) per-segment LUT build + one-hot accumulate, ascending mi
-    adc = jnp.zeros((bb, c), jnp.float32)
-    for mi in range(m):
-        lut = lut_segment(qseg[:, mi], cbs[mi])                 # [Bb, 256]
-        onehot = (code_blk[:, :, mi][:, :, None]
-                  == jax.lax.broadcasted_iota(jnp.int32, (bb, c, 256), 2)
-                  ).astype(jnp.float32)
-        adc = adc + jax.lax.dot_general(
-            onehot, lut, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-    adc = jnp.where(cand < 0, jnp.inf, adc)
+    def segment(mi, adc):
+        # one PQ segment for every row of the block: select each
+        # candidate's codeword entry of the row's LUT and sum over the
+        # 256 codewords (one non-zero addend — exact)
+        lut = lut_ref[mi]                         # [256, Bb] f32
+        codes = codes_ref[mi]                     # [Bb, Ct] int32
+        look = jnp.zeros((bb, c), jnp.float32)
+        for b in range(bb):
+            hit = code_iota == codes[b:b + 1, :]                  # [256, Ct]
+            val = jnp.sum(jnp.where(hit, lut[:, b:b + 1], 0.0), axis=0,
+                          keepdims=True)                          # [1, Ct]
+            look = jnp.where(row == b, val, look)
+        return adc + look
 
-    # (4) streaming top-k: n_cand masked argmin rounds; first-index
-    # tie-break among NOT-yet-taken lanes == lax.top_k's stable order
-    def select(k, st):
-        taken, out_ids = st
-        masked = jnp.where(taken, jnp.inf, adc)
-        v = jnp.min(masked, axis=1, keepdims=True)
-        pick = (masked == v) & ~taken
-        j = jnp.argmax(pick, axis=1)                            # [Bb]
-        ids = jnp.take_along_axis(cand, j[:, None], axis=1)
-        out_ids = jax.lax.dynamic_update_slice(out_ids, ids, (0, k))
-        taken = taken | (jax.lax.broadcasted_iota(jnp.int32, (bb, c), 1)
-                         == j[:, None])
-        return taken, out_ids
-
-    _, out_ids = jax.lax.fori_loop(
-        0, n_cand, select,
-        (jnp.zeros((bb, c), bool), jnp.zeros((bb, n_cand), jnp.int32)))
-    out_ref[...] = out_ids
+    out_ref[...] = jax.lax.fori_loop(0, codes_ref.shape[0], segment,
+                                     jnp.zeros((bb, c), jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=("n_cand", "block_b", "interpret"))
@@ -159,28 +148,38 @@ def adc_rank_pallas(q: jax.Array, codebooks: jax.Array, cand: jax.Array,
                     codes: jax.Array, *, n_cand: int, block_b: int = 8,
                     interpret: bool | None = None) -> jax.Array:
     """Padded-shape kernel entry: q rows must be a block_b multiple
-    (padding handled by ops.adc_rank).  Same contract as `adc_rank_jnp`,
-    bit-identical output.  `interpret=None` derives the mode from the
-    runtime platform (compiled on TPU, interpret elsewhere)."""
+    (padding handled by ops.adc_rank; block_b a multiple of 8).  Same
+    contract as `adc_rank_jnp`, bit-identical output.  `interpret=None`
+    derives the mode from the runtime platform (compiled on TPU,
+    interpret elsewhere)."""
     if interpret is None:
         interpret = default_interpret()
     b, dim = q.shape
     m, _, seg = codebooks.shape
-    n = codes.shape[0]
     c = cand.shape[1]
     assert b % block_b == 0 and m * seg == dim and n_cand <= c
+    nblk = b // block_b
+    block_c = min(_BLOCK_C, -(-c // 128) * 128)
+    c_pad = -(-c // block_c) * block_c
+    qseg = q.reshape(b, m, seg)
+    luts = jnp.stack([lut_segment(qseg[:, mi], codebooks[mi])
+                      for mi in range(m)])                     # [m, b, 256]
+    # per query tile, the LUT is [m, 256, Bb]: codewords on sublanes, so
+    # a query's table is one lane column inside the kernel
+    luts = luts.reshape(m, nblk, block_b, 256).transpose(1, 0, 3, 2)
+    code_blk = codes[jnp.maximum(cand, 0)].astype(jnp.int32)   # [b, C, m]
+    code_blk = jnp.transpose(code_blk, (2, 0, 1))               # [m, b, C]
+    code_blk = jnp.pad(code_blk, ((0, 0), (0, 0), (0, c_pad - c)))
 
-    kernel = functools.partial(_kernel, n_cand=n_cand)
-    return pl.pallas_call(
-        kernel,
-        grid=(b // block_b,),
+    adc = pl.pallas_call(
+        _kernel,
+        grid=(nblk, c_pad // block_c),
         in_specs=[
-            pl.BlockSpec((block_b, m, seg), lambda i: (i, 0, 0)),
-            pl.BlockSpec((m, 256, seg), lambda i: (0, 0, 0)),
-            pl.BlockSpec((block_b, c), lambda i: (i, 0)),
-            pl.BlockSpec((n, m), lambda i: (0, 0)),
+            pl.BlockSpec((None, m, 256, block_b), lambda i, j: (i, 0, 0, 0)),
+            pl.BlockSpec((m, block_b, block_c), lambda i, j: (0, i, j)),
         ],
-        out_specs=pl.BlockSpec((block_b, n_cand), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, n_cand), jnp.int32),
+        out_specs=pl.BlockSpec((block_b, block_c), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((b, c_pad), jnp.float32),
         interpret=interpret,
-    )(q.reshape(b, m, seg), codebooks, cand, codes)
+    )(luts, code_blk)
+    return _rank_tail(adc[:, :c], cand, n_cand)

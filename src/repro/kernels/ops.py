@@ -49,16 +49,20 @@ def blocked_hist(q, r, eps_grid, *, metric: str, block_r: int, nr_valid: int):
     """Traceable lax.scan over R blocks: O(block) memory, XLA-fused
     compare+reduce. r.shape[0] must be a block_r multiple. This is the
     per-shard compute of the engine's sharded sweep (core/engine.py) —
-    keep it jit-free so it composes under shard_map / outer jits."""
+    keep it jit-free so it composes under shard_map / outer jits. The
+    dot runs at HIGHEST precision, like the kernel and the oracle, so
+    eps-boundary verdicts agree across backends on TPU."""
     nr = r.shape[0]
     nblk = nr // block_r
-    rb = r.reshape(nblk, block_r, r.shape[1])
     eps = eps_grid.astype(jnp.float32)
     qf = q.astype(jnp.float32)
 
-    def body(carry, xs):
-        blk, base = xs
-        dots = qf @ blk.astype(jnp.float32).T
+    def body(carry, base):
+        # a row slice, not a [nblk, block_r, d] reshape of R: R is read in
+        # place whatever its layout (reshaping a column-major R copies it)
+        blk = jax.lax.dynamic_slice_in_dim(r, base, block_r, axis=0)
+        dots = jnp.matmul(qf, blk.astype(jnp.float32).T,
+                          precision=jax.lax.Precision.HIGHEST)
         if metric == "cosine":
             d = 1.0 - dots
         else:
@@ -70,7 +74,7 @@ def blocked_hist(q, r, eps_grid, *, metric: str, block_r: int, nr_valid: int):
 
     init = jnp.zeros((q.shape[0], eps.shape[0]), jnp.int32)
     bases = jnp.arange(nblk) * block_r
-    out, _ = jax.lax.scan(body, init, (rb, bases))
+    out, _ = jax.lax.scan(body, init, bases)
     return out
 
 
@@ -139,7 +143,8 @@ def lsh_bucket_gather(tables, pb, *, backend: str = "auto",
                       block_q: int = 128) -> jax.Array:
     """LSH member-table gather + multiprobe dedup (kernels/lsh_gather.py).
 
-    tables int32 [l, B, cap], pb int32 [q, l, n_probes].  Returns int32
+    tables int32 [l, cap, B] (`lsh_gather.bucket_minor`), pb int32
+    [q, l, n_probes].  Returns int32
     [q, l*n_probes*cap] candidate ids; duplicate probe blocks blanked to
     -1.  All backends are bit-identical by construction (integer-only);
     "ref"/"jnp" take the direct-gather formulation, "pallas" the fused

@@ -7,27 +7,33 @@ method reduce to "count neighbors of each query within each eps".
 
 TPU adaptation (vs the paper's CPU loop / a CUDA candidate-list port):
   * The (Q_blk x R_blk) distance tile is an MXU matmul on unit vectors:
-    d_cos = 1 - q.r,  d_l2 = sqrt(2 - 2 q.r), so one bf16 matmul with f32
-    accumulation yields the whole tile.
+    d_cos = 1 - q.r,  d_l2 = sqrt(2 - 2 q.r), so one f32 matmul at
+    HIGHEST precision yields the whole tile (the TPU default would round
+    both operands to bf16 and move distances across an eps boundary).
   * The m-bin eps histogram is fused into the same VMEM residency: the
     distance tile is compared against ONE eps at a time (a per-eps masked
     accumulate on the VPU) and the per-eps counts land in an int32
     [Q_blk, m] block, so the m-candidate grid used by ATCS costs a single
-    sweep over R instead of m sweeps.  The compare working set is a
-    single [Q_blk, R_blk] bool — it used to be a [Q_blk, R_blk,
-    eps_chunk] broadcast, which at the default tile was the largest
-    temporary in the kernel and capped block_r at 512.
+    sweep over R instead of m sweeps.  The eps grid sits in SMEM and the
+    loop reads eps[c] as a scalar; the count lands in lane c through an
+    iota select — Mosaic lowers neither a dynamic lane slice nor a
+    dynamic lane update.  The compare working set is a single [Q_blk,
+    R_blk] bool.
   * Grid is (q_blocks, r_blocks) with the r axis innermost ("arbitrary"
     semantics): the output block for a fixed q block is revisited across r
     steps and accumulated in place — the canonical Pallas reduction layout.
+  * R is read in [Br, D] row tiles, so it must be stored row-major.  On
+    TPU an f32 [N, D] array whose D is not a multiple of 128 defaults to
+    the column-major (compact) layout, and XLA would copy all of R into a
+    row-major one before every sweep; the engine therefore pins R
+    row-major when it uploads it (`engine._put_row_major`).
 
 VMEM budget at the widened tile (Bq=256, Br=1024, d<=1024, m<=128):
-  q tile 256x1024 f32 = 1 MB, r tile 1024x1024 f32 = 4 MB, distance tile
-  256x1024 f32 = 1 MB, out 256x128 i32 = 0.125 MB, per-eps compare
-  256x1024 bool = 0.25 MB  =>  ~6.4 MB < 16 MB VMEM (the old eps-chunk
-  broadcast was 256x512x8 bool = 1 MB at Br=512 and would have been 2 MB
-  at Br=1024 — the per-eps accumulate is what lets block_r grow to 1024
-  with headroom).
+  q tile 256x1024 f32 = 1 MB and r tile 1024x1024 f32 = 4 MB (each
+  double-buffered), distance tile 256x1024 f32 = 1 MB, out 256x128 i32 =
+  0.125 MB, per-eps compare 256x1024 = 0.25 MB  =>  ~12.5 MB < the 16 MB
+  scoped VMEM default (tests/test_tpu_compile.py compiles d=960 at
+  Br=1024 for a v5e).
 
 `eps_chunk` survives only as the eps-grid PADDING quantum (callers pad m
 to a multiple of it so one executable serves nearby grid sizes); the
@@ -40,6 +46,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def default_interpret() -> bool:
@@ -53,7 +60,7 @@ def default_interpret() -> bool:
 
 def _kernel(q_ref, r_ref, eps_ref, out_ref, *, metric: str, nr_valid: int,
             block_r: int):
-    i, j = pl.program_id(0), pl.program_id(1)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -61,7 +68,11 @@ def _kernel(q_ref, r_ref, eps_ref, out_ref, *, metric: str, nr_valid: int,
 
     q = q_ref[...].astype(jnp.float32)            # [Bq, D]
     r = r_ref[...].astype(jnp.float32)            # [Br, D]
+    # HIGHEST: full-f32 products, so a distance at an eps boundary falls
+    # on the same side as in the `ref` oracle (a default-precision TPU
+    # matmul rounds both operands to bf16 first)
     dots = jax.lax.dot_general(q, r, (((1,), (1,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)  # [Bq, Br]
     if metric == "cosine":
         d = 1.0 - dots
@@ -74,18 +85,19 @@ def _kernel(q_ref, r_ref, eps_ref, out_ref, *, metric: str, nr_valid: int,
     r_index = j * block_r + jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
     d = jnp.where(r_index < nr_valid, d, jnp.inf)
 
-    eps = eps_ref[0, :]                           # [m_padded] f32
-    m_padded = eps.shape[0]
-    acc = jnp.zeros(out_ref.shape, jnp.int32)     # [Bq, m_padded]
+    m_padded = out_ref.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 1)
 
     def body(c, acc):
         # per-eps masked accumulate: the compare temporary is one
-        # [Bq, Br] bool, not the old [Bq, Br, eps_chunk] broadcast
-        e = jax.lax.dynamic_slice(eps, (c,), (1,))
-        cnt = jnp.sum(d <= e[0], axis=1, dtype=jnp.int32)   # [Bq]
-        return jax.lax.dynamic_update_slice(acc, cnt[:, None], (0, c))
+        # [Bq, Br] bool; eps is a scalar read from SMEM and the count
+        # lands in lane c through a select (no dynamic lane slicing)
+        cnt = jnp.sum((d <= eps_ref[c]).astype(jnp.int32), axis=1,
+                      keepdims=True)                          # [Bq, 1]
+        return jnp.where(lane == c, cnt, acc)
 
-    acc = jax.lax.fori_loop(0, m_padded, body, acc)
+    acc = jax.lax.fori_loop(0, m_padded, body,
+                            jnp.zeros(out_ref.shape, jnp.int32))
     out_ref[...] += acc
 
 
@@ -109,7 +121,6 @@ def range_count_hist_pallas(q: jax.Array, r: jax.Array, eps_grid: jax.Array,
     nr_valid = nr if nr_valid is None else nr_valid
     if interpret is None:
         interpret = default_interpret()
-    eps2d = eps_grid.astype(jnp.float32).reshape(1, m)
 
     grid = (nq // block_q, nr // block_r)
     kernel = functools.partial(_kernel, metric=metric, nr_valid=nr_valid,
@@ -120,9 +131,9 @@ def range_count_hist_pallas(q: jax.Array, r: jax.Array, eps_grid: jax.Array,
         in_specs=[
             pl.BlockSpec((block_q, d), lambda i, j: (i, 0)),
             pl.BlockSpec((block_r, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, m), lambda i, j: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((block_q, m), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nq, m), jnp.int32),
         interpret=interpret,
-    )(q, r, eps2d)
+    )(q, r, eps_grid.astype(jnp.float32))

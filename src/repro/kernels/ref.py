@@ -11,8 +11,12 @@ import jax.numpy as jnp
 
 
 def pair_distances(q: jax.Array, r: jax.Array, metric: str) -> jax.Array:
-    """Distances between unit-normalized rows of q [nq,d] and r [nr,d]."""
-    dots = jnp.einsum("qd,rd->qr", q.astype(jnp.float32), r.astype(jnp.float32))
+    """Distances between unit-normalized rows of q [nq,d] and r [nr,d].
+
+    The dot runs at HIGHEST precision: on TPU the default rounds f32
+    operands to bf16, which moves distances across an eps boundary."""
+    dots = jnp.einsum("qd,rd->qr", q.astype(jnp.float32), r.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
     if metric == "cosine":
         return 1.0 - dots
     if metric == "l2":

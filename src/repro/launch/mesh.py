@@ -1,13 +1,13 @@
-"""Mesh construction — the single version-compatible entry point.
+"""Mesh construction — the single mesh entry point.
 
-Every mesh in the codebase (production, CPU smoke, elastic rebuilds, the
-engine's data mesh, tests) is built through `make_mesh` here.  JAX moved the
-`axis_types=` kwarg / `jax.sharding.AxisType` enum in post-0.4.x releases;
-`make_mesh` feature-detects them and falls back cleanly, so no module may
-touch `jax.sharding.AxisType` or pass `axis_types=` directly (DESIGN.md §7).
-The policy is enforced mechanically: xlint's mesh-policy rule (DESIGN.md
-§12, `make lint`) rejects raw `jax.sharding.Mesh(...)` / `jax.make_mesh`
-calls, `AxisType` access, and `axis_types=` kwargs everywhere but here.
+Every mesh in the codebase (production, CPU smoke, the engine's data
+mesh, the ring topology's join mesh, tests) is built through `make_mesh`
+here, which pins every axis to `jax.sharding.AxisType.Auto` so sharding
+propagation behaves the same whatever default newer JAX releases pick.
+The policy is enforced mechanically: xlint's mesh-policy rule
+(DESIGN.md §7, §12, `make lint`) rejects raw `jax.sharding.Mesh(...)` /
+`jax.make_mesh` calls, `AxisType` access, and `axis_types=` kwargs
+everywhere but here.
 
 Functions, not module constants — importing this module never touches jax
 device state.
@@ -20,33 +20,19 @@ import jax
 import numpy as np
 
 
-def _axis_types_kw(n_axes: int) -> dict:
-    """`{"axis_types": (Auto,) * n}` on JAX versions that have the enum."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
               devices=None) -> jax.sharding.Mesh:
-    """Version-compatible mesh builder.
+    """Build a mesh with every axis in `AxisType.Auto` mode.
 
     shape/axes as for `jax.make_mesh`.  Pass `devices` (flat sequence, length
     prod(shape)) to pin an explicit device order (elastic rebuilds); otherwise
     jax picks a performant order over all local devices.
     """
-    kw = _axis_types_kw(len(axes))
+    axis_types = (jax.sharding.AxisType.Auto,) * len(axes)
     if devices is not None:
         devs = np.asarray(devices).reshape(tuple(shape))
-        try:
-            return jax.sharding.Mesh(devs, tuple(axes), **kw)
-        except TypeError:       # enum exists but ctor predates the kwarg
-            return jax.sharding.Mesh(devs, tuple(axes))
-    try:
-        return jax.make_mesh(tuple(shape), tuple(axes), **kw)
-    except TypeError:
-        return jax.make_mesh(tuple(shape), tuple(axes))
+        return jax.sharding.Mesh(devs, tuple(axes), axis_types=axis_types)
+    return jax.make_mesh(tuple(shape), tuple(axes), axis_types=axis_types)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -77,9 +63,9 @@ def make_join_mesh(data: int | None = None, r: int = 1) -> jax.sharding.Mesh:
     `r` is the index-sharding axis (R row-shards over it under
     `topology="ring"`; per-device R memory drops by this factor), `data`
     the query-sharding axis; `data=None` spreads the remaining devices
-    (len(devices) // r). Built through the mandatory `make_mesh` compat
-    path. Raises ValueError when the local device count cannot host the
-    requested shape — at build time, not inside a sweep."""
+    (len(devices) // r). Built through the mandatory `make_mesh`. Raises
+    ValueError when the local device count cannot host the requested
+    shape — at build time, not inside a sweep."""
     devs = jax.devices()
     if r < 1:
         raise ValueError(f"make_join_mesh(r={r}): r must be >= 1")

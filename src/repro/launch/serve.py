@@ -36,6 +36,7 @@ import numpy as np
 
 from repro.data import load_dataset
 from repro.serve import Gateway, TenantClass
+from repro.utils import init_compile_cache
 
 
 def batch_stats(b: int, res, true_counts: np.ndarray,
@@ -172,16 +173,15 @@ def build_gateway(args, R, metric: str) -> Gateway:
         R, classes, metric=metric, filter="xling",
         filter_opts=dict(tau=args.tau, xdt="fpr", estimator=args.estimator,
                          epochs=args.epochs),
-        backend="jnp", topology=args.topology, r_shards=args.r_shards,
+        backend="auto", topology=args.topology, r_shards=args.r_shards,
         cache_key=(args.dataset, args.n), eps_quantum=args.eps_quantum,
         max_batch_rows=args.max_batch_rows,
         mutable=args.mutate_trace is not None)
 
 
-def main():
-    """CLI entry point: compile the flags into a Gateway, replay the
-    query stream as round-robin per-tenant requests, and print the
-    per-request lines, aggregate summary, and the gateway report."""
+def build_parser() -> argparse.ArgumentParser:
+    """The serve CLI's flags (`main` parses them; `build_gateway` compiles
+    them into a Gateway)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="glove")
     ap.add_argument("--n", type=int, default=4000)
@@ -233,8 +233,16 @@ def main():
                          "gateway mutable and computes each request's "
                          "recall oracle against the logical set at "
                          "submit time")
-    args = ap.parse_args()
+    return ap
 
+
+def main():
+    """CLI entry point: compile the flags into a Gateway, replay the
+    query stream as round-robin per-tenant requests, and print the
+    per-request lines, aggregate summary, and the gateway report."""
+    args = build_parser().parse_args()
+
+    init_compile_cache()
     R, S, spec = load_dataset(args.dataset, n=args.n)
     t0 = time.time()
     gw = build_gateway(args, R, spec.metric)

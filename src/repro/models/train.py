@@ -37,13 +37,26 @@ def fit_regressor(params, apply_fn: Callable, X: np.ndarray, y: np.ndarray,
 
     rng = np.random.default_rng(seed)
     nb = max(1, n // batch_size)
+
+    @jax.jit
+    def epoch(params, state, Xj, yj, wj, perm):
+        # one dispatch per epoch: the minibatch loop is a scan over the
+        # same per-step update (a per-step host loop is bound by dispatch
+        # latency on an accelerator)
+        def body(carry, b):
+            params, state, _ = carry
+            idx = jax.lax.dynamic_slice_in_dim(perm, b * batch_size,
+                                               batch_size)
+            return step(params, state, Xj[idx], yj[idx], wj[idx]), None
+
+        carry = (params, state, jnp.float32(jnp.inf))
+        return jax.lax.scan(body, carry, jnp.arange(nb))[0]
+
     loss = np.inf
     Xj, yj, wj = jnp.asarray(X), jnp.asarray(y), jnp.asarray(weights)
     for ep in range(epochs):
         perm = jnp.asarray(rng.permutation(n))
-        for b in range(nb):
-            idx = jax.lax.dynamic_slice_in_dim(perm, b * batch_size, batch_size)
-            params, state, loss = step(params, state, Xj[idx], yj[idx], wj[idx])
+        params, state, loss = epoch(params, state, Xj, yj, wj, perm)
         if log_every and (ep + 1) % log_every == 0:
             print(f"  epoch {ep+1}/{epochs} loss={float(loss):.5f}")
     return params, float(loss)

@@ -13,7 +13,25 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-CACHE_DIR = os.environ.get("REPRO_CACHE", os.path.join(os.path.dirname(__file__), "..", "..", ".cache"))
+_REPO_ROOT = os.path.normpath(os.path.join(os.path.dirname(__file__), "..", ".."))
+CACHE_DIR = os.environ.get("REPRO_CACHE", os.path.join(_REPO_ROOT, ".cache"))
+#: JAX persistent compilation cache used when JAX_COMPILATION_CACHE_DIR is
+#: unset: one fixed directory inside the checkout (git ignores `.cache/`),
+#: so a later process of the same checkout finds what an earlier one
+#: compiled (the path is part of the cache key — a moving path never hits)
+COMPILE_CACHE_DIR = os.path.join(_REPO_ROOT, ".cache", "jax_compile")
+
+
+def init_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    With `JAX_COMPILATION_CACHE_DIR` set, that directory is the cache and
+    no other path is configured; otherwise the cache goes to
+    `COMPILE_CACHE_DIR`.  Called by the entry points that compile real
+    programs (the serve CLI, `chip_smoke.py`), never at import."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or COMPILE_CACHE_DIR
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def cache_path(*key: Any, ext: str = "npz") -> str:
@@ -78,12 +96,9 @@ def memoize_device_fn(obj, key, build):
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """Version-compatible `Compiled.cost_analysis()`: JAX 0.4.x returns a
-    one-dict list (per executable), newer versions the dict itself."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """`Compiled.cost_analysis()` as a plain dict (empty when the backend
+    reports none)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def round_up(x: int, m: int) -> int:

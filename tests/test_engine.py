@@ -562,8 +562,8 @@ def test_ring_topology_subprocess_8dev():
 
 # ------------------------------------------------------------- mesh compat
 def test_make_mesh_no_axistype_dependency():
-    """The compat helper must build meshes on JAX versions without
-    jax.sharding.AxisType (the installed 0.4.x) and with explicit devices."""
+    """The one mesh builder must build meshes over the default device
+    order and over explicit devices, with every axis in Auto mode."""
     import jax
     from repro.launch.mesh import (make_cpu_mesh, make_data_mesh,
                                    make_join_mesh, make_mesh)
@@ -571,6 +571,7 @@ def test_make_mesh_no_axistype_dependency():
     assert m.axis_names == ("data", "model")
     m2 = make_mesh((1,), ("data",), devices=jax.devices()[:1])
     assert m2.devices.shape == (1,)
+    assert [t.name for t in m2.axis_types] == ["Auto"]
     assert make_cpu_mesh().axis_names == ("data", "model")
     assert make_data_mesh().axis_names == ("data",)
     assert make_join_mesh(data=1, r=1).axis_names == ("r", "data")

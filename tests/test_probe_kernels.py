@@ -26,7 +26,7 @@ from repro.core.engine import JoinEngine
 from repro.core import probe as probe_mod
 from repro.kernels import ops
 from repro.kernels.adc_rank import adc_rank_chain, adc_rank_jnp
-from repro.kernels.lsh_gather import (lsh_bucket_gather_jnp,
+from repro.kernels.lsh_gather import (bucket_minor, lsh_bucket_gather_jnp,
                                       lsh_probe_dup_mask)
 from repro.kernels.range_count import default_interpret
 
@@ -71,10 +71,11 @@ def test_lsh_gather_pallas_matches_jnp_exactly(shape):
     tables = rng.integers(-1, 900, size=(l, B, cap)).astype(np.int32)
     pb = rng.integers(0, B, size=(q, l, n_probes)).astype(np.int32)
     pb[..., -1] = pb[..., 0]          # the pad schedule repeats probe 0
-    a = np.asarray(ops.lsh_bucket_gather(jnp.asarray(tables),
-                                         jnp.asarray(pb), backend="jnp"))
-    b = np.asarray(ops.lsh_bucket_gather(jnp.asarray(tables),
-                                         jnp.asarray(pb), backend="pallas"))
+    stored = jnp.asarray(bucket_minor(tables))
+    a = np.asarray(ops.lsh_bucket_gather(stored, jnp.asarray(pb),
+                                         backend="jnp"))
+    b = np.asarray(ops.lsh_bucket_gather(stored, jnp.asarray(pb),
+                                         backend="pallas"))
     np.testing.assert_array_equal(a, b)
     raw = tables[np.arange(l)[None, :, None], pb].reshape(q, -1)
     for i in range(q):
@@ -90,10 +91,11 @@ def test_lsh_gather_large_ids_exact():
     tables = np.broadcast_to(ids, (2, 8, 4)).copy()
     rng = np.random.default_rng(9)
     pb = rng.integers(0, 8, size=(5, 2, 3)).astype(np.int32)
-    a = np.asarray(ops.lsh_bucket_gather(jnp.asarray(tables),
-                                         jnp.asarray(pb), backend="jnp"))
-    b = np.asarray(ops.lsh_bucket_gather(jnp.asarray(tables),
-                                         jnp.asarray(pb), backend="pallas"))
+    stored = jnp.asarray(bucket_minor(tables))
+    a = np.asarray(ops.lsh_bucket_gather(stored, jnp.asarray(pb),
+                                         backend="jnp"))
+    b = np.asarray(ops.lsh_bucket_gather(stored, jnp.asarray(pb),
+                                         backend="pallas"))
     np.testing.assert_array_equal(a, b)
     for v in (2**30 - 1, 2**24 + 1, 16_777_217):
         assert v in set(b.ravel().tolist())
@@ -108,7 +110,7 @@ def test_lsh_gather_empty_buckets_and_full_dup():
     pb = rng.integers(0, B, size=(q, l, n_probes)).astype(np.int32)
     for be in ("jnp", "pallas"):
         out = np.asarray(ops.lsh_bucket_gather(
-            jnp.asarray(empty), jnp.asarray(pb), backend=be))
+            jnp.asarray(bucket_minor(empty)), jnp.asarray(pb), backend=be))
         assert (out == -1).all(), be
     # every probe identical -> dup mask true for all but probe 0
     pb_dup = np.repeat(pb[:, :, :1], n_probes, axis=2)
@@ -116,7 +118,7 @@ def test_lsh_gather_empty_buckets_and_full_dup():
     assert not dup[..., 0].any() and dup[..., 1:].all()
     tables = rng.integers(-1, 100, size=(l, B, cap)).astype(np.int32)
     out = np.asarray(ops.lsh_bucket_gather(
-        jnp.asarray(tables), jnp.asarray(pb_dup),
+        jnp.asarray(bucket_minor(tables)), jnp.asarray(pb_dup),
         backend="pallas")).reshape(q, l, n_probes, cap)
     assert (out[:, :, 1:] == -1).all()
     np.testing.assert_array_equal(
@@ -367,3 +369,38 @@ def test_overlapped_ring_adds_no_host_syncs_2dev():
         "    np.testing.assert_array_equal(a, b)\n"
         "print('RING_GUARD_OK')\n")
     assert "RING_GUARD_OK" in _run_forced_devices(code)
+
+
+def test_bucket_minor_layout_round_trips():
+    """`bucket_minor` transposes to [.., cap, B_pad] with -1 padding
+    buckets, tile-aligned once the table spans more than one tile."""
+    from repro.kernels.lsh_gather import padded_buckets
+    rng = np.random.default_rng(3)
+    tables = rng.integers(-1, 500, size=(2, 3, 2500, 5)).astype(np.int32)
+    out = bucket_minor(tables)
+    assert out.shape == (2, 3, 5, 4096) and out.dtype == np.int32
+    assert padded_buckets(2500) == 4096 and padded_buckets(300) == 384
+    np.testing.assert_array_equal(np.swapaxes(out[..., :2500], -1, -2),
+                                  tables)
+    assert (out[..., 2500:] == -1).all()
+
+
+def test_shard_lsh_tables_partitions_exactly():
+    """Each shard's table holds exactly the global ids of its row range,
+    bucket by bucket, at the smallest capacity that fits every shard."""
+    rng = np.random.default_rng(4)
+    l, B, cap, shards, rows = 3, 40, 9, 4, 25
+    tables = rng.integers(-1, shards * rows, size=(l, B, cap)).astype(
+        np.int32)
+    out = probe_mod._shard_lsh_tables(tables, shards, rows)
+    need = max(int(((tables >= s * rows) & (tables < (s + 1) * rows))
+                   .sum(axis=-1).max()) for s in range(shards))
+    assert out.shape == (shards, l, B, need) and out.dtype == np.int32
+    for s in range(shards):
+        for t in range(l):
+            for b in range(B):
+                got = out[s, t, b]
+                want = tables[t, b]
+                want = want[(want >= s * rows) & (want < (s + 1) * rows)]
+                np.testing.assert_array_equal(np.sort(got[got >= 0]),
+                                              np.sort(want))
