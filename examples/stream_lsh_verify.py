@@ -62,9 +62,7 @@ for b, res in enumerate(results):
     print(f"batch {b}: queries={len(batches[b])} "
           f"searched={res.n_searched} "
           f"skipped={1 - res.n_searched / len(batches[b]):.2%} "
-          f"recall={recall:.3f} "
-          f"t_filter={res.t_filter * 1e3:.1f}ms "
-          f"t_search={res.t_search * 1e3:.1f}ms")
+          f"recall={recall:.3f}")
 
 print(f"stream recall vs exact sweep: "
       f"{total_found / max(total_true, 1):.3f} "

@@ -38,7 +38,8 @@ DEV_NAME_RE = re.compile(r".*dev$")
 HOT_FUNCTIONS = {
     "src/repro/core/engine.py": {
         "JoinEngine._stage_filter", "JoinEngine._stage_probe",
-        "JoinEngine._commit_verify", "JoinEngine.stream",
+        "JoinEngine._commit_verify", "JoinEngine._dispatch_verify",
+        "JoinEngine.stream",
         "PendingJoin.result", "StreamSession.submit", "StreamSession.flush",
         "StreamSession._commit_probed", "StreamSession._advance_staged",
     },

@@ -41,14 +41,13 @@ remain as thin legacy shims over `JoinPlan`.
 from __future__ import annotations
 
 import json
-import time
 from dataclasses import dataclass, field
 from typing import (Any, Callable, Iterable, Iterator, Optional, Protocol,
                     runtime_checkable)
 
 import numpy as np
 
-from repro.core.engine import VERIFY_BACKENDS, JoinEngine
+from repro.core.engine import VERIFY_BACKENDS, JoinEngine, _span
 from repro.core.topology import resolve_topology
 from repro.core.joins import JOINS, make_join
 from repro.core.joins.lsbf import LSBF
@@ -238,19 +237,13 @@ def _filter_label(f) -> Optional[str]:
 # ============================================================== the plan
 @dataclass
 class JoinResult:
-    """Per-call join outcome: exact-at-candidates neighbor counts plus the
-    filter/search timing split and provenance metadata."""
+    """Per-call join outcome: exact-at-candidates neighbor counts plus
+    provenance metadata.  Where the time goes is in a profiler trace:
+    the call's `join.` spans (DESIGN.md §12)."""
     counts: np.ndarray
     n_queries: int
     n_searched: int
-    t_filter: float
-    t_search: float
     meta: dict = field(default_factory=dict)
-
-    @property
-    def t_total(self) -> float:
-        """Filter + search wall-clock for this call."""
-        return self.t_filter + self.t_search
 
     def recall_vs(self, true_counts: np.ndarray) -> float:
         """Pair-level recall: found pairs over true pairs (count-based —
@@ -748,11 +741,10 @@ class JoinPlan:
         frac = getattr(self._route_searcher(), "overflow_frac", None)
         return None if frac is None else float(frac)
 
-    def _wrap(self, res, n: int, eps: float, t_host: float) -> JoinResult:
+    def _wrap(self, res, n: int, eps: float) -> JoinResult:
         st = self._built
         return JoinResult(
             counts=res.counts, n_queries=n, n_searched=res.n_searched,
-            t_filter=res.t_filter + t_host, t_search=res.t_search,
             meta={"eps": eps, "tau": getattr(st.filter, "tau", 0),
                   "base": getattr(st.base, "name", "?"),
                   "filter": _filter_label(st.filter),
@@ -764,20 +756,21 @@ class JoinPlan:
         """One synchronous join pass: fused filter (or uploaded host
         verdicts) -> compact -> verify through the engine. Under
         `on(plan="auto")` the first call plans (measure-then-choose,
-        DESIGN.md §16) and every call delegates to the chosen plan."""
+        DESIGN.md §16) and every call delegates to the chosen plan.
+        The call is the span `join.run`."""
         if self._exec["plan"] == "auto":
             return self._planned_delegate(Q, eps).run(Q, eps)
         self.build()
         Q = np.asarray(Q, np.float32)
-        t0 = time.perf_counter()
-        predict, threshold = self._filter_state(eps)
-        verdicts = None if predict is not None else self._host_verdicts(Q, eps)
-        t_host = time.perf_counter() - t0
-        res = self._built.engine.filtered_join(
-            Q, float(eps), predict=predict, threshold=threshold,
-            verdicts=verdicts, block=self._exec["block"],
-            verify=self._built.verify_route, probe=self._exec["probe"])
-        return self._wrap(res, len(Q), eps, t_host)
+        with _span("join.run"):
+            predict, threshold = self._filter_state(eps)
+            verdicts = (None if predict is not None
+                        else self._host_verdicts(Q, eps))
+            res = self._built.engine.filtered_join(
+                Q, float(eps), predict=predict, threshold=threshold,
+                verdicts=verdicts, block=self._exec["block"],
+                verify=self._built.verify_route, probe=self._exec["probe"])
+            return self._wrap(res, len(Q), eps)
 
     def stream(self, batches: Iterable[np.ndarray], eps: float, *,
                depth: Optional[int] = None) -> Iterator[JoinResult]:
@@ -1082,38 +1075,34 @@ class PlanSession:
         plan.build()
         self._plan = plan
         self.eps = float(eps)
-        t0 = time.perf_counter()
         self._predict, self._threshold = plan._filter_state(eps)
-        self._t_host = time.perf_counter() - t0  # one-time XDT selection
         self._sess = plan._built.engine.stream_session(
             eps, predict=self._predict, threshold=self._threshold,
             verify=plan._built.verify_route, depth=depth,
             block=plan._exec["block"], probe=plan._exec["probe"])
-        self._pending: list[tuple[int, float]] = []  # FIFO (n, host cost)
+        self._pending: list[int] = []   # FIFO batch sizes
 
     def _emit(self, results) -> list[JoinResult]:
-        out = []
-        for res in results:
-            n, th = self._pending.pop(0)
-            out.append(self._plan._wrap(res, n, self.eps, th))
-        return out
+        return [self._plan._wrap(res, self._pending.pop(0), self.eps)
+                for res in results]
 
     def submit(self, Q: np.ndarray) -> list[JoinResult]:
         """Feed one query batch; returns older batches' results whose
         readback completed under the depth bound (host filter verdicts are
-        computed here when the filter has no device form)."""
+        computed here when the filter has no device form).  The call is
+        the span `join.submit`."""
         Q = np.asarray(Q, np.float32)
-        t1 = time.perf_counter()
-        verdicts = (None if self._predict is not None
-                    else self._plan._host_verdicts(Q, self.eps))
-        th = self._t_host + (time.perf_counter() - t1)
-        self._t_host = 0.0              # charge XDT selection to batch 0
-        self._pending.append((len(Q), th))
-        return self._emit(self._sess.submit(Q, verdicts=verdicts))
+        with _span("join.submit"):
+            verdicts = (None if self._predict is not None
+                        else self._plan._host_verdicts(Q, self.eps))
+            self._pending.append(len(Q))
+            return self._emit(self._sess.submit(Q, verdicts=verdicts))
 
     def flush(self) -> list[JoinResult]:
-        """Drain barrier: all remaining results, in submission order."""
-        return self._emit(self._sess.flush())
+        """Drain barrier: all remaining results, in submission order.
+        The call is the span `join.flush`."""
+        with _span("join.flush"):
+            return self._emit(self._sess.flush())
 
     def set_depth(self, depth: int) -> None:
         """Retarget the in-flight bound mid-stream (adaptive depth,

@@ -70,7 +70,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import functools
-import time
+import itertools
 import weakref
 from dataclasses import dataclass
 from typing import Callable, Iterable, Iterator, Optional
@@ -257,8 +257,6 @@ class EngineJoinResult:
     """Result of one filtered-join batch through the engine."""
     counts: np.ndarray      # int32 [n] neighbor counts (0 for skipped)
     n_searched: int         # queries that reached verification
-    t_filter: float
-    t_search: float
     verify: str = "exact"   # label of the backend that produced `counts`
     probe: Optional[str] = None   # "device" | "host" | None (exact sweep)
 
@@ -338,8 +336,18 @@ def host_sync_guard(*allowed: str):
         _SYNC_GUARDS.pop()
 
 
+def _span(name: str, **counts):
+    """A host span of the join pipeline, `join.<stage>`, with integer
+    `counts` as its arguments (DESIGN.md §12).  It is a
+    `jax.profiler.TraceAnnotation`: under a profiler session it lands in
+    the trace on the clock of the device events, so every device-idle gap
+    can be put against the program step the host was in; with no session
+    it costs about a microsecond."""
+    return jax.profiler.TraceAnnotation(name, **counts)
+
+
 @contextlib.contextmanager
-def _allowed_transfer(kind: str):
+def _allowed_transfer(kind: str, **counts):
     """Scope of one DECLARED per-batch device→host sync (DESIGN.md §12).
 
     The exact and device-probe routes declare exactly two such points —
@@ -352,9 +360,14 @@ def _allowed_transfer(kind: str):
     §11 "only two host transfers per batch" claim as an enforced runtime
     property, not just instrumentation.  Host-probe syncs ("verdicts" /
     "probe") deliberately do NOT open an allow window: under the guard
-    the host route fails, which is what proves the guard is live."""
+    the host route fails, which is what proves the guard is live.
+
+    The scope is also the span `join.sync.<kind>` (with `counts`, e.g.
+    the batch id), so every declared wait shows in a profiler trace under
+    the name the guard uses."""
     _note_host_sync(kind)
-    with jax.transfer_guard_device_to_host("allow"):
+    with _span(f"join.sync.{kind}", **counts), \
+            jax.transfer_guard_device_to_host("allow"):
         yield
 
 
@@ -413,9 +426,12 @@ class _StagedBatch:
     `_commit_verify` as a fallback) reads it; on a device-probe route
     `_stage_probe` additionally fills `qpos_dev` / `idx_dev` / `cand_dev`
     and sets `probe` to the placed probe that produced them. `world` is
-    the submit-time `_WorldView` snapshot (DESIGN.md §13)."""
-    __slots__ = ("Q", "n", "eps", "qdev", "eps_dev", "pos_dev", "n_pos_dev",
-                 "n_pos", "t_stage", "probe", "qpos_dev", "idx_dev",
+    the submit-time `_WorldView` snapshot (DESIGN.md §13). `batch` is
+    the engine's sequence number of the batch, carried by every span of
+    it, so one batch can be followed across the calls that stage,
+    verify and read it."""
+    __slots__ = ("Q", "n", "eps", "batch", "qdev", "eps_dev", "pos_dev",
+                 "n_pos_dev", "n_pos", "probe", "qpos_dev", "idx_dev",
                  "cand_dev", "capacity", "world")
 
 
@@ -423,32 +439,24 @@ class PendingJoin:
     """Stage-2 handle for one in-flight batch.
 
     Verification is dispatched and the device→host copy is running;
-    `result()` is the only blocking point and is idempotent. Async-path
-    timing convention: `t_search` = dispatch-side cost + whatever wait
-    `result()` actually observed (≈0 when the pipeline hid the readback).
-    """
+    `result()` is the only blocking point and is idempotent."""
 
     def __init__(self, finalize: Callable[[], np.ndarray], *, verify: str,
-                 n_searched: int, t_filter: float, t_dispatch: float,
-                 probe: Optional[str] = None):
+                 n_searched: int, batch: int, probe: Optional[str] = None):
         self._finalize = finalize
         self._verify = verify
         self._probe = probe
         self._n_searched = n_searched
-        self._t_filter = t_filter
-        self._t_dispatch = t_dispatch
+        self._batch = batch
         self._res: Optional[EngineJoinResult] = None
 
     def result(self) -> EngineJoinResult:
         """Materialize (blocking if the device is still busy)."""
         if self._res is None:
-            t0 = time.perf_counter()
-            with _allowed_transfer("result"):
+            with _allowed_transfer("result", batch=self._batch):
                 counts = self._finalize()
-            self._res = EngineJoinResult(
-                counts, self._n_searched, self._t_filter,
-                self._t_dispatch + (time.perf_counter() - t0), self._verify,
-                self._probe)
+            self._res = EngineJoinResult(counts, self._n_searched,
+                                         self._verify, self._probe)
         return self._res
 
 
@@ -609,6 +617,8 @@ class JoinEngine:
         #: Bounded: distinct radii / shape buckets per engine are few.
         self._eps_scalar_cache: dict = {}
         self._allpos_cache: dict = {}
+        #: batch sequence numbers, carried by every span of a batch
+        self._batch_ids = itertools.count()
         # ---- dynamic-R state (DESIGN.md §13) ----------------------------
         #: compact automatically once delta_frac reaches this fraction of
         #: |R| (None = manual compaction only; JoinPlan.mutable sets it)
@@ -896,9 +906,7 @@ class JoinEngine:
         shards queries on) — bounds recompiles AND keeps per-shard shapes
         block-aligned."""
         Q = np.asarray(Q, np.float32)
-        quantum = self.topology.q_row_quantum(self.block_q, self.mesh,
-                                              self.data_axis)
-        return _pad_rows_np(Q, _bucket_size(len(Q), quantum))
+        return _pad_rows_np(Q, self.padded_rows(len(Q)))
 
     def _put_q(self, qp: np.ndarray) -> jax.Array:
         if self._q_sharding is not None:
@@ -979,56 +987,67 @@ class JoinEngine:
         estimator/XDT program (or uploads precomputed host verdicts), and
         returns a `_StagedBatch` handle. Nothing here waits on the device,
         so batch k+1 can be staged while batch k's verification is still
-        executing — the double-buffering half of DESIGN.md §5."""
+        executing — the double-buffering half of DESIGN.md §5.
+
+        Spans: `join.stage` (`batch`, `rows` and `h2d_bytes`, the padded
+        query buffer's bytes) around
+        `join.stage.pad`, `join.stage.upload` and, with a device filter,
+        `join.stage.filter` (the filter program's dispatch)."""
         st = _StagedBatch()
         st.Q = np.asarray(Q, np.float32)
         st.n = len(st.Q)
         st.eps = float(eps)
-        t0 = time.perf_counter()
-        qp = self._pad_q(st.Q)
-        st.qdev = self._put_q(qp)
-        st.eps_dev = self._eps_scalar_cache.get(st.eps)
-        if st.eps_dev is None:
-            if len(self._eps_scalar_cache) > 64:
-                self._eps_scalar_cache.clear()
-            st.eps_dev = jnp.asarray(st.eps, jnp.float32)
-            self._eps_scalar_cache[st.eps] = st.eps_dev
-        if predict is None and verdicts is None:
-            # no filter: verify everything — the all-positive mask and its
-            # count depend only on (padded rows, batch rows), so the
-            # stream reuses one device-resident pair per shape bucket
-            cached = self._allpos_cache.get((len(qp), st.n))
-            if cached is None:
-                if len(self._allpos_cache) > 64:
-                    self._allpos_cache.clear()
+        st.batch = next(self._batch_ids)
+        padded = self.padded_rows(st.n)
+        with _span("join.stage", batch=st.batch, rows=st.n,
+                   h2d_bytes=padded * st.Q.shape[1] * st.Q.itemsize):
+            with _span("join.stage.pad"):
+                qp = _pad_rows_np(st.Q, padded)
+            with _span("join.stage.upload"):
+                st.qdev = self._put_q(qp)
+            st.eps_dev = self._eps_scalar_cache.get(st.eps)
+            if st.eps_dev is None:
+                if len(self._eps_scalar_cache) > 64:
+                    self._eps_scalar_cache.clear()
+                st.eps_dev = jnp.asarray(st.eps, jnp.float32)
+                self._eps_scalar_cache[st.eps] = st.eps_dev
+            if predict is None and verdicts is None:
+                # no filter: verify everything — the all-positive mask and
+                # its count depend only on (padded rows, batch rows), so
+                # the stream reuses one device-resident pair per shape
+                # bucket
+                cached = self._allpos_cache.get((len(qp), st.n))
+                if cached is None:
+                    if len(self._allpos_cache) > 64:
+                        self._allpos_cache.clear()
+                    pos_host = np.zeros((len(qp),), bool)
+                    pos_host[:st.n] = True
+                    cached = ((jax.device_put(pos_host, self._q_sharding)
+                               if self._q_sharding is not None
+                               else jnp.asarray(pos_host)),
+                              jnp.asarray(st.n, jnp.int32))
+                    self._allpos_cache[(len(qp), st.n)] = cached
+                st.pos_dev, st.n_pos_dev = cached
+                st.n_pos = st.n
+            elif verdicts is not None:
                 pos_host = np.zeros((len(qp),), bool)
-                pos_host[:st.n] = True
-                cached = ((jax.device_put(pos_host, self._q_sharding)
-                           if self._q_sharding is not None
-                           else jnp.asarray(pos_host)),
-                          jnp.asarray(st.n, jnp.int32))
-                self._allpos_cache[(len(qp), st.n)] = cached
-            st.pos_dev, st.n_pos_dev = cached
-            st.n_pos = st.n
-        elif verdicts is not None:
-            pos_host = np.zeros((len(qp),), bool)
-            pos_host[:st.n] = np.asarray(verdicts, bool)
-            st.n_pos = int(pos_host.sum())
-            st.pos_dev = (jax.device_put(pos_host, self._q_sharding)
-                          if self._q_sharding is not None
-                          else jnp.asarray(pos_host))
-            st.n_pos_dev = jnp.asarray(st.n_pos, jnp.int32)
-        else:
-            params, _ = predict
-            prog = self._filter_program(predict)
-            _, st.pos_dev, st.n_pos_dev = prog(
-                params, st.qdev, st.eps_dev,
-                jnp.asarray(threshold, jnp.float32),
-                jnp.asarray(st.n, jnp.int32))
-            st.n_pos = None                 # read at commit time
-        st.probe = None                     # set by _stage_probe (§11)
-        st.world = self._world()            # submit-time snapshot (§13)
-        st.t_stage = time.perf_counter() - t0
+                pos_host[:st.n] = np.asarray(verdicts, bool)
+                st.n_pos = int(pos_host.sum())
+                st.pos_dev = (jax.device_put(pos_host, self._q_sharding)
+                              if self._q_sharding is not None
+                              else jnp.asarray(pos_host))
+                st.n_pos_dev = jnp.asarray(st.n_pos, jnp.int32)
+            else:
+                params, _ = predict
+                prog = self._filter_program(predict)
+                with _span("join.stage.filter"):
+                    _, st.pos_dev, st.n_pos_dev = prog(
+                        params, st.qdev, st.eps_dev,
+                        jnp.asarray(threshold, jnp.float32),
+                        jnp.asarray(st.n, jnp.int32))
+                st.n_pos = None                 # read at commit time
+            st.probe = None                     # set by _stage_probe (§11)
+            st.world = self._world()            # submit-time snapshot (§13)
         return st
 
     # ------------------------------------------- stage 2: probe dispatch
@@ -1092,10 +1111,12 @@ class JoinEngine:
         dispatch the compact-gather and probe programs, producing the
         candidate ids on device while the PREVIOUS batch's verification
         is still executing. Host-probe routes only perform the count
-        read here; the probing itself stays in `_commit_verify`."""
-        t0 = time.perf_counter()
+        read here; the probing itself stays in `_commit_verify`.
+
+        Spans: `join.sync.n_pos` around the count read, and `join.probe`
+        (`batch`) around the gather and probe dispatch."""
         if st.n_pos is None:
-            with _allowed_transfer("n_pos"):
+            with _allowed_transfer("n_pos", batch=st.batch):
                 # xlint: allow-host-sync(n_pos: per-batch count read)
                 st.n_pos = int(st.n_pos_dev)
         if placed is not None:
@@ -1110,11 +1131,11 @@ class JoinEngine:
                 # whole padded batch
                 st.capacity = min(_bucket_size(st.n_pos, 64),
                                   st.qdev.shape[0])
-                gather = _gather_program(self.mesh, self.data_axis)
-                st.qpos_dev, st.idx_dev = gather(st.qdev, st.pos_dev,
-                                                 capacity=st.capacity)
-                st.cand_dev = placed.probe(st.qpos_dev)
-        st.t_stage += time.perf_counter() - t0
+                with _span("join.probe", batch=st.batch):
+                    gather = _gather_program(self.mesh, self.data_axis)
+                    st.qpos_dev, st.idx_dev = gather(st.qdev, st.pos_dev,
+                                                     capacity=st.capacity)
+                    st.cand_dev = placed.probe(st.qpos_dev)
         return st
 
     # ------------------------------------- stage 3: verify dispatch (commit)
@@ -1132,28 +1153,47 @@ class JoinEngine:
         searcher object (see `_check_verify`): any join method's
         `candidates()` can route the compacted positives through the
         device candidate-verification path — the Searcher half of the
-        DESIGN.md §9 protocol contract."""
+        DESIGN.md §9 protocol contract.
+
+        Spans: `join.sync.n_pos` around a count read not made in stage 2,
+        and `join.verify` (`batch`, `n_pos`, `capacity`: the rows verify
+        runs on) from the count to the end of the dispatch; a batch with
+        no positives verifies nothing and opens none."""
         label = _check_verify(verify)       # fail fast, not data-dependently
-        t0 = time.perf_counter()
         if st.n_pos is None:                # direct callers skipped stage 2
-            with _allowed_transfer("n_pos"):
+            with _allowed_transfer("n_pos", batch=st.batch):
                 # xlint: allow-host-sync(n_pos: per-batch count read)
                 st.n_pos = int(st.n_pos_dev)
-        t_filter = st.t_stage + (time.perf_counter() - t0)
         n, n_pos = st.n, st.n_pos
-        w = st.world                        # submit-time logical set (§13)
         probe_label = None if verify == "exact" else \
             ("device" if st.probe is not None else "host")
 
         if n_pos == 0:
             return PendingJoin(lambda: np.zeros((n,), np.int32), verify=label,
-                               n_searched=0, t_filter=t_filter,
-                               t_dispatch=0.0, probe=probe_label)
+                               n_searched=0, batch=st.batch,
+                               probe=probe_label)
 
-        t1 = time.perf_counter()
         if verify == "exact":
             capacity = min(_bucket_size(n_pos, block or self.block),
                            st.qdev.shape[0])
+        elif st.probe is not None:
+            capacity = st.capacity
+        else:
+            capacity = n_pos    # the host routes verify the positives as is
+        with _span("join.verify", batch=st.batch, n_pos=n_pos,
+                   capacity=capacity):
+            finalize = self._dispatch_verify(st, verify, label, capacity)
+        return PendingJoin(finalize, verify=label, n_searched=n_pos,
+                           batch=st.batch, probe=probe_label)
+
+    def _dispatch_verify(self, st: "_StagedBatch", verify: VerifySpec,
+                         label: str,
+                         capacity: int) -> Callable[[], np.ndarray]:
+        """Dispatch the verification of `st`'s `n_pos > 0` positives on
+        its route and return the `finalize` that reads the counts back
+        (`_commit_verify`'s body)."""
+        n, w = st.n, st.world               # submit-time logical set (§13)
+        if verify == "exact":
             cprog = _compact_program(self.mesh, self.data_axis, self.backend,
                                      self.metric, self.block_q, self.block_r,
                                      self.nr, self.topology)
@@ -1251,10 +1291,7 @@ class JoinEngine:
                         # xlint: allow-host-sync(result: readback in PendingJoin.result)
                         counts = counts + np.asarray(adj_dev)[:n]
                     return counts
-        t_dispatch = time.perf_counter() - t1
-        return PendingJoin(finalize, verify=label, n_searched=n_pos,
-                           t_filter=t_filter, t_dispatch=t_dispatch,
-                           probe=probe_label)
+        return finalize
 
     # ------------------------------------------------ verification backends
     def verifier(self, name: str, **params):

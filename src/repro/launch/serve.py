@@ -45,9 +45,8 @@ def batch_stats(b: int, res, true_counts: np.ndarray,
     serving form, kept for plan-level debugging and the probe tests):
     filter skip rate, verification recall vs the exact oracle, probe
     placement + the verify index's build-time candidate-loss budget
-    (DESIGN.md §11), the delta occupancy at submit time when a mutation
-    trace is being replayed (DESIGN.md §13), and the filter/search
-    timing split."""
+    (DESIGN.md §11), and the delta occupancy at submit time when a
+    mutation trace is being replayed (DESIGN.md §13)."""
     out = {
         "batch": b,
         "queries": int(res.n_queries),
@@ -57,8 +56,6 @@ def batch_stats(b: int, res, true_counts: np.ndarray,
         "verify": res.meta.get("verify", "exact"),
         "probe": res.meta.get("probe"),
         "overflow_frac": res.meta.get("overflow_frac"),
-        "t_filter_ms": res.t_filter * 1e3,
-        "t_search_ms": res.t_search * 1e3,
     }
     if delta_frac is not None:
         out["delta_frac"] = float(delta_frac)

@@ -1,0 +1,243 @@
+"""The join pipeline's host spans (`engine._span`, DESIGN.md §12).
+
+Covers: the span tree `JoinPlan.run` opens on the filter route, the
+exact route and a batch with no positives; the counts each span carries
+(`rows`, `h2d_bytes`, `n_pos`, `capacity`) against the call's own
+numbers and the query buffer actually uploaded; one `batch` id tying a streamed batch's stage,
+verify and result spans together across the `submit` calls of a depth-2
+session; the declared syncs (`_note_host_sync`) as `join.sync.<kind>`
+spans; a real profiler session on the CPU returning the spans with
+their counts as event stats; and the module names the trace gives the
+filter and verify programs.
+"""
+import contextlib
+import dataclasses
+import glob
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.core import JoinPlan
+from repro.core import api, engine
+from repro.core.engine import _compact_program
+
+EPS = 0.45
+
+
+def _first_coord(params, X):
+    return X[:, 0] - params
+
+
+class FirstCoordFilter:
+    """A Filter-protocol object with a device form: keeps the queries
+    whose first coordinate exceeds `cut`."""
+    tau = 0
+
+    def __init__(self, cut: float):
+        self.cut = cut
+
+    def verdicts(self, Q, eps):
+        return np.asarray(Q)[:, 0] > self.cut
+
+    def device_filter(self, eps):
+        return (jnp.float32(self.cut), _first_coord), 0.0
+
+
+@dataclasses.dataclass
+class Span:
+    name: str
+    counts: dict
+    parent: "Span | None"
+
+
+class Recorder:
+    """Stands in for `_span` and `_note_host_sync`: every span opened,
+    with its counts and the span it opened in, and every sync noted."""
+
+    def __init__(self):
+        self.spans: list[Span] = []
+        self.syncs: list[str] = []
+        self._open: list[Span] = []
+
+    @contextlib.contextmanager
+    def span(self, name, **counts):
+        s = Span(name, counts, self._open[-1] if self._open else None)
+        self.spans.append(s)
+        self._open.append(s)
+        try:
+            yield
+        finally:
+            self._open.pop()
+
+    def named(self, name):
+        return [s for s in self.spans if s.name == name]
+
+    def names(self):
+        return [s.name for s in self.spans]
+
+    def children(self, parent):
+        return [s.name for s in self.spans if s.parent is parent]
+
+
+@pytest.fixture
+def rec(monkeypatch):
+    r = Recorder()
+    monkeypatch.setattr(engine, "_span", r.span)
+    monkeypatch.setattr(api, "_span", r.span)
+    monkeypatch.setattr(engine, "_note_host_sync", r.syncs.append)
+    return r
+
+
+@pytest.fixture
+def staged(monkeypatch):
+    """Every `_StagedBatch` the engine stages, in order."""
+    out = []
+    stage = engine.JoinEngine._stage_filter
+
+    def recording(self, *a, **k):
+        out.append(stage(self, *a, **k))
+        return out[-1]
+    monkeypatch.setattr(engine.JoinEngine, "_stage_filter", recording)
+    return out
+
+
+@pytest.fixture(scope="module")
+def data():
+    rng = np.random.default_rng(3)
+
+    def unit(n, d=16):
+        x = rng.normal(size=(n, d)).astype(np.float32)
+        return x / np.linalg.norm(x, axis=1, keepdims=True)
+    return unit(700), unit(300)
+
+
+def _plan(R, filt):
+    return (JoinPlan(R, "cosine").search("naive").on(backend="jnp")
+            .filter(filt).build())
+
+
+def test_filter_route_span_tree(data, rec, staged):
+    R, Q = data
+    plan = _plan(R, FirstCoordFilter(0.0))
+    res = plan.run(Q, EPS)
+    assert 0 < res.n_searched < len(Q)
+    (run,) = rec.named("join.run")
+    assert run.parent is None and run.counts == {}
+    assert rec.children(run) == ["join.stage", "join.sync.n_pos",
+                                 "join.verify", "join.sync.result"]
+    (stage,) = rec.named("join.stage")
+    assert rec.children(stage) == ["join.stage.pad", "join.stage.upload",
+                                   "join.stage.filter"]
+    (st,) = staged
+    assert stage.counts == {"batch": st.batch, "rows": len(Q),
+                            "h2d_bytes": st.qdev.nbytes}
+    assert st.qdev.shape[0] > len(Q)            # the padding is counted
+    (verify,) = rec.named("join.verify")
+    assert verify.counts["n_pos"] == res.n_searched
+    assert verify.counts["capacity"] >= res.n_searched
+    batch = stage.counts["batch"]
+    assert verify.counts["batch"] == batch
+    for kind in ("n_pos", "result"):
+        assert rec.named(f"join.sync.{kind}")[0].counts == {"batch": batch}
+    assert rec.syncs == ["n_pos", "result"]
+
+
+def test_exact_route_reads_no_count(data, rec):
+    """Without a filter the count is known when the batch is staged: no
+    `join.sync.n_pos`, and no filter program to dispatch."""
+    R, Q = data
+    res = _plan(R, "none").run(Q, EPS)
+    assert res.n_searched == len(Q)
+    assert "join.sync.n_pos" not in rec.names()
+    assert "join.stage.filter" not in rec.names()
+    (verify,) = rec.named("join.verify")
+    assert verify.counts["n_pos"] == len(Q)
+    assert verify.counts["capacity"] >= len(Q)
+    assert rec.syncs == ["result"]
+
+
+def test_no_positives_opens_no_verify(data, rec):
+    R, Q = data
+    res = _plan(R, FirstCoordFilter(2.0)).run(Q, EPS)   # unit rows: none
+    assert res.n_searched == 0 and not res.counts.any()
+    assert "join.verify" not in rec.names()
+    assert rec.names()[-1] == "join.sync.result"
+
+
+def test_session_batches_share_one_id(data, rec):
+    """A depth-2 session stages, verifies and reads each batch in
+    different `submit` calls; the `batch` id ties its spans together."""
+    R, Q = data
+    plan = _plan(R, FirstCoordFilter(0.0))
+    sess = plan.session(EPS, depth=2)
+    batches = [Q[i:i + 60] for i in range(0, 300, 60)]
+    out = []
+    for b in batches:
+        out += sess.submit(b)
+    out += sess.flush()
+    assert len(out) == len(batches)
+    stages = rec.named("join.stage")
+    ids = [s.counts["batch"] for s in stages]
+    assert len(set(ids)) == len(batches)
+    assert all(s.parent.name == "join.submit" for s in stages)
+    verifies = {s.counts["batch"]: s for s in rec.named("join.verify")}
+    results = [s.counts["batch"] for s in rec.named("join.sync.result")]
+    assert results == ids                   # FIFO, one read per batch
+    for i, r in zip(ids, out):
+        if r.n_searched:
+            assert verifies[i].counts["n_pos"] == r.n_searched
+        else:
+            assert i not in verifies
+    # a batch's verify opens in a later submit than its stage
+    first = stages[0]
+    assert verifies[ids[0]].parent is not first.parent
+    assert rec.named("join.flush")[0].parent is None
+
+
+def test_profiler_session_returns_the_spans(data, tmp_path):
+    """Under a real profiler session the spans land in the trace with
+    their counts as event stats."""
+    R, Q = data
+    plan = _plan(R, FirstCoordFilter(0.0))
+    plan.run(Q, EPS)                                    # compile first
+    with jax.profiler.trace(str(tmp_path)):
+        res = plan.run(Q, EPS)
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    found = {}
+    for plane in pd.planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("join."):
+                    found.setdefault(ev.name, []).append(dict(ev.stats))
+    assert {"join.run", "join.stage", "join.stage.pad", "join.stage.upload",
+            "join.stage.filter", "join.sync.n_pos", "join.verify",
+            "join.sync.result"} <= set(found)
+    (stage,) = found["join.stage"]
+    padded = plan.engine.padded_rows(len(Q))
+    assert stage["rows"] == len(Q)
+    assert stage["h2d_bytes"] == padded * Q.shape[1] * 4
+    assert found["join.verify"][0]["n_pos"] == res.n_searched
+
+
+def test_program_module_names(data):
+    """The trace names a program's module by its jitted function; the
+    benchmark's device metrics find the filter and verify modules under
+    these names."""
+    R, Q = data
+    eng = engine.JoinEngine(R, "cosine", backend="jnp")
+    predict = (jnp.float32(0.0), _first_coord)
+    st = eng._stage_filter(Q, EPS, predict=predict, threshold=0.0)
+    lowered = eng._filter_program(predict).lower(
+        predict[0], st.qdev, st.eps_dev, jnp.float32(0.0),
+        jnp.int32(st.n))
+    assert "module @jit_program" in lowered.as_text()
+    cprog = _compact_program(eng.mesh, eng.data_axis, eng.backend,
+                             eng.metric, eng.block_q, eng.block_r, eng.nr,
+                             eng.topology)
+    w = st.world
+    lowered = cprog.lower(st.qdev, st.pos_dev, st.n_pos_dev, w.Rdev,
+                          st.eps_dev, w.nrv, capacity=st.qdev.shape[0])
+    assert "module @jit_prog " in lowered.as_text()
