@@ -116,6 +116,47 @@ def _put_row_major(x: np.ndarray, sharding) -> jax.Array:
     return jax.device_put(x, Format(Layout(tuple(range(x.ndim))), sharding))
 
 
+#: leaves of at least this many elements reach the filter program as
+#: buffers of their own (`_stack_by_shape`)
+_STACK_BELOW = 4096
+
+
+def _stack_by_shape(params) -> tuple[tuple, tuple]:
+    """The filter program's parameter buffers (DESIGN.md §4): the leaves
+    of `params` under `_STACK_BELOW` elements stacked, one buffer per
+    (shape, dtype) group, on a new leading axis; larger leaves as they
+    are.
+
+    Returns `(buffers, layout)`; `layout = (treedef, slots)` says that
+    leaf i of the pytree is `buffers[g]` for `(g, None) = slots[i]` and
+    `buffers[g][j]` for `(g, j)`, which a traced program reads back with
+    static indices.  The weight matrices stay whole: a slice of a
+    stacked buffer is fused into the matrix product that reads it, and
+    the compiler then no longer prefetches the weight into on-chip
+    memory ahead of its use (on a TPU v5e, stacking the paper RMI's
+    weights too made its 29 us filter program 22 us slower).  The small
+    leaves, biases and the last layer's column, are most of the leaves
+    and cost nothing stacked."""
+    leaves, treedef = jax.tree_util.tree_flatten(params)
+    index: dict = {}
+    buffers: list = []
+    slots = []
+    for leaf in leaves:
+        if np.size(leaf) >= _STACK_BELOW:
+            slots.append((len(buffers), None))
+            buffers.append(leaf)
+            continue
+        key = (np.shape(leaf), jnp.result_type(leaf))
+        if key not in index:
+            index[key] = len(buffers)
+            buffers.append([])
+        g = index[key]
+        slots.append((g, len(buffers[g])))
+        buffers[g].append(leaf)
+    return (tuple(jnp.stack(b) if isinstance(b, list) else b
+                  for b in buffers), (treedef, tuple(slots)))
+
+
 def _pad_rows_np(x: np.ndarray, n: int) -> np.ndarray:
     if x.shape[0] >= n:
         return x
@@ -609,13 +650,17 @@ class JoinEngine:
         self._q_sharding = None if mesh is None else NamedSharding(
             mesh, self.topology.q_spec(data_axis))
         self._upload_R(R)
+        #: filter programs by (fn, parameter layout), and per fn the
+        #: stacked parameters of its current leaves (`_filter_program`)
         self._filter_progs: dict = {}
+        self._filter_params: dict = {}
         #: per-batch staging constants (DESIGN.md §5): streamed batches
-        #: re-stage the same radius scalar and — on unfiltered plans —
-        #: the same all-positive mask every submit; both depend only on
-        #: (value, shape bucket), so one upload serves the whole stream.
-        #: Bounded: distinct radii / shape buckets per engine are few.
-        self._eps_scalar_cache: dict = {}
+        #: re-stage the same radius, XDT threshold and row count and — on
+        #: unfiltered plans — the same all-positive mask every submit;
+        #: each depends only on (value, shape bucket), so one upload
+        #: serves the whole stream.  Bounded: distinct values per engine
+        #: are few.
+        self._scalar_cache: dict = {}
         self._allpos_cache: dict = {}
         #: batch sequence numbers, carried by every span of a batch
         self._batch_ids = itertools.count()
@@ -963,20 +1008,54 @@ class JoinEngine:
 
     # ------------------------------------------------- fused filtered join
     def _filter_program(self, predict):
-        # keyed by the fn object itself (estimators memoize it): survives
-        # refits without id-reuse aliasing, and the key pins the fn alive
-        _, fn = predict
-        prog = self._filter_progs.get(fn)
-        if prog is None:
-            def program(params, q, eps, thr, n_valid):
-                X = jnp.concatenate(
-                    [q, jnp.full((q.shape[0], 1), eps, jnp.float32)], axis=1)
-                preds = fn(params, X)
-                pos = (preds > thr) & (jnp.arange(q.shape[0]) < n_valid)
-                return preds, pos, jnp.sum(pos, dtype=jnp.int32)
-            prog = jax.jit(program)
-            self._filter_progs[fn] = prog
-        return prog
+        """`(program, stacked)` for `predict = (params, fn)`: the jitted
+        filter program and `params` as `_stack_by_shape` gives them, its
+        first argument (DESIGN.md §4).  The stacked buffers are built
+        once per fn and set of leaves: the entry keeps the leaves alive,
+        so their ids identify them, and a refit, which brings new
+        leaves, replaces it.  Programs are keyed by the fn itself
+        (estimators memoize it) and the layout, so a refit of the same
+        shapes reuses the compiled program."""
+        params, fn = predict
+        leaves = jax.tree_util.tree_leaves(params)
+        ids = tuple(map(id, leaves))
+        entry = self._filter_params.get(fn)
+        if entry is None or entry[0] != ids:
+            if entry is None and len(self._filter_params) >= 8:
+                self._filter_params.clear()     # each holds stacked copies
+            stacked, layout = _stack_by_shape(params)
+            prog = self._filter_progs.get((fn, layout))
+            if prog is None:
+                treedef, slots = layout
+
+                def program(stacked, q, eps, thr, n_valid):
+                    params = treedef.unflatten(
+                        [stacked[g] if j is None else stacked[g][j]
+                         for g, j in slots])
+                    X = jnp.concatenate(
+                        [q, jnp.full((q.shape[0], 1), eps, jnp.float32)],
+                        axis=1)
+                    preds = fn(params, X)
+                    pos = (preds > thr) & (jnp.arange(q.shape[0]) < n_valid)
+                    return preds, pos, jnp.sum(pos, dtype=jnp.int32)
+                prog = jax.jit(program)
+                self._filter_progs[(fn, layout)] = prog
+            entry = (ids, leaves, prog, stacked)
+            self._filter_params[fn] = entry
+        return entry[2], entry[3]
+
+    def _scalar(self, value, dtype) -> tuple[jax.Array, int]:
+        """The device scalar `value` as `dtype`, uploaded on first use
+        and reused after (`_scalar_cache`), and how many uploads this
+        call made (0 or 1)."""
+        key = (np.dtype(dtype).name, value)
+        dev = self._scalar_cache.get(key)
+        if dev is not None:
+            return dev, 0
+        if len(self._scalar_cache) > 64:
+            self._scalar_cache.clear()
+        dev = self._scalar_cache[key] = jnp.asarray(value, dtype)
+        return dev, 1
 
     # --------------------------------------------- stage 1: filter dispatch
     def _stage_filter(self, Q, eps: float, *, predict=None, threshold=None,
@@ -992,7 +1071,10 @@ class JoinEngine:
         Spans: `join.stage` (`batch`, `rows` and `h2d_bytes`, the padded
         query buffer's bytes) around
         `join.stage.pad`, `join.stage.upload` and, with a device filter,
-        `join.stage.filter` (the filter program's dispatch)."""
+        `join.stage.filter` (the filter program's dispatch; `args`, the
+        device buffers it passes, and `uploads`, the scalars this batch
+        uploaded: 0 once a stream's radius, threshold and row count are
+        cached)."""
         st = _StagedBatch()
         st.Q = np.asarray(Q, np.float32)
         st.n = len(st.Q)
@@ -1005,12 +1087,7 @@ class JoinEngine:
                 qp = _pad_rows_np(st.Q, padded)
             with _span("join.stage.upload"):
                 st.qdev = self._put_q(qp)
-            st.eps_dev = self._eps_scalar_cache.get(st.eps)
-            if st.eps_dev is None:
-                if len(self._eps_scalar_cache) > 64:
-                    self._eps_scalar_cache.clear()
-                st.eps_dev = jnp.asarray(st.eps, jnp.float32)
-                self._eps_scalar_cache[st.eps] = st.eps_dev
+            st.eps_dev, uploads = self._scalar(st.eps, jnp.float32)
             if predict is None and verdicts is None:
                 # no filter: verify everything — the all-positive mask and
                 # its count depend only on (padded rows, batch rows), so
@@ -1038,13 +1115,13 @@ class JoinEngine:
                               else jnp.asarray(pos_host))
                 st.n_pos_dev = jnp.asarray(st.n_pos, jnp.int32)
             else:
-                params, _ = predict
-                prog = self._filter_program(predict)
-                with _span("join.stage.filter"):
+                prog, stacked = self._filter_program(predict)
+                thr_dev, up_thr = self._scalar(float(threshold), jnp.float32)
+                n_dev, up_n = self._scalar(st.n, jnp.int32)
+                with _span("join.stage.filter", args=len(stacked) + 4,
+                           uploads=uploads + up_thr + up_n):
                     _, st.pos_dev, st.n_pos_dev = prog(
-                        params, st.qdev, st.eps_dev,
-                        jnp.asarray(threshold, jnp.float32),
-                        jnp.asarray(st.n, jnp.int32))
+                        stacked, st.qdev, st.eps_dev, thr_dev, n_dev)
                 st.n_pos = None                 # read at commit time
             st.probe = None                     # set by _stage_probe (§11)
             st.world = self._world()            # submit-time snapshot (§13)

@@ -20,7 +20,8 @@ import numpy as np
 import pytest
 
 from repro.core import XlingConfig, XlingFilter, make_join
-from repro.core.engine import JoinEngine, _bucket_size, sharded_range_count_hist
+from repro.core.engine import (_STACK_BELOW, JoinEngine, _bucket_size,
+                               _stack_by_shape, sharded_range_count_hist)
 from repro.core.xjoin import FilteredJoin
 from repro.kernels import ops, ref
 
@@ -285,11 +286,11 @@ def test_stream_staging_constant_caches(world):
     batches = [Q[:64], Q[64:128], Q[128:]]
     got = np.concatenate([r.counts for r in eng.stream(batches, 0.8)])
     np.testing.assert_array_equal(got, want)
-    assert len(eng._eps_scalar_cache) == 1      # one radius staged once
+    assert len(eng._scalar_cache) == 1          # one radius staged once
     keys = set(eng._allpos_cache)
     assert len(keys) == 2                       # 64-row + 29-row buckets
     st = eng._stage_filter(Q[:64], 0.8)
-    assert st.eps_dev is eng._eps_scalar_cache[0.8]
+    assert st.eps_dev is eng._scalar_cache[("float32", 0.8)]
     assert st.pos_dev is eng._allpos_cache[(st.qdev.shape[0], 64)][0]
     assert set(eng._allpos_cache) == keys       # no new upload
 
@@ -307,6 +308,114 @@ def test_engine_filter_program_cache_stable(world):
     for _ in range(3):
         fj.run(Q, 0.8)
     assert len(base.engine._filter_progs) == 1
+
+
+def _fitted_estimator(name, R, seed=0):
+    """A registry estimator fitted for one epoch on `R`'s rows with a
+    radius column, at widths small enough for the CPU, with weight
+    matrices on both sides of `_STACK_BELOW`."""
+    from repro.models import make_estimator
+    est = make_estimator(name, R.shape[1] + 1, widths=(16, 256, 16, 4),
+                         epochs=1, seed=seed)
+    _refit(est, R, seed)
+    return est
+
+
+def _refit(est, R, seed):
+    rng = np.random.default_rng(seed)
+    X = np.concatenate([R, rng.uniform(0.2, 1.8, (len(R), 1))],
+                       axis=1).astype(np.float32)
+    est.fit(X, rng.integers(0, 40, len(R)).astype(np.float32))
+
+
+def _filter_on_leaves(predict, st, thr):
+    """The filter program's body over the original parameter leaves,
+    jitted: what the engine ran before it stacked them."""
+    import jax
+    import jax.numpy as jnp
+    params, fn = predict
+
+    @jax.jit
+    def program(params, q, eps, thr, n_valid):
+        X = jnp.concatenate(
+            [q, jnp.full((q.shape[0], 1), eps, jnp.float32)], axis=1)
+        preds = fn(params, X)
+        pos = (preds > thr) & (jnp.arange(q.shape[0]) < n_valid)
+        return preds, pos, jnp.sum(pos, dtype=jnp.int32)
+    return program(params, st.qdev, st.eps_dev, jnp.float32(thr),
+                   jnp.int32(st.n))
+
+
+@pytest.mark.parametrize("name", ["rmi", "nn", "selnet", "linear"])
+def test_stacked_filter_program_matches_leaves(world, name):
+    """The filter program takes the estimator's small parameter leaves
+    stacked by (shape, dtype), its large ones whole, and rebuilds the
+    pytree inside the trace: `preds`, `pos` and `n_pos` are
+    bit-identical to the same program over the original leaves, and the
+    program gets one buffer per large leaf and per group of small
+    ones."""
+    import jax
+    import jax.numpy as jnp
+    R, Q, _ = world
+    predict = _fitted_estimator(name, R).device_predict_fn()
+    X = np.concatenate([Q, np.full((len(Q), 1), 0.8, np.float32)], axis=1)
+    thr = float(np.median(np.asarray(jax.jit(predict[1])(predict[0], X))))
+    eng = JoinEngine(R, "l2", backend="jnp")
+    st = eng._stage_filter(Q, 0.8, predict=predict, threshold=thr)
+    prog, stacked = eng._filter_program(predict)
+    leaves = jax.tree_util.tree_leaves(predict[0])
+    big = [x for x in leaves if np.size(x) >= _STACK_BELOW]
+    small = {(np.shape(x), x.dtype) for x in leaves
+             if np.size(x) < _STACK_BELOW}
+    assert len(stacked) == len(big) + len(small)
+    for b in big:
+        assert any(s is b for s in stacked)     # passed as it is
+    got = prog(stacked, st.qdev, st.eps_dev, jnp.float32(thr),
+               jnp.int32(st.n))
+    want = _filter_on_leaves(predict, st, thr)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    np.testing.assert_array_equal(np.asarray(st.pos_dev), np.asarray(want[1]))
+    assert int(st.n_pos_dev) == int(want[2])
+    assert 0 < int(want[2]) < st.n          # the threshold splits the batch
+
+
+def test_paper_rmi_filter_buffers():
+    """The paper's RMI (stages 1/2/4 of the 512/512/256/128 MLP, d = 200):
+    70 leaves reach the filter program as 33 buffers, its 28 weight
+    matrices whole and the rest in 5 stacked groups."""
+    from repro.models.rmi import RMIEstimator
+    est = RMIEstimator(201, stage_sizes=(1, 2, 4))
+    buffers, (_, slots) = _stack_by_shape([list(s) for s in est.stages])
+    assert (len(slots), len(buffers)) == (70, 33)
+    assert sum(j is None for _, j in slots) == 28
+
+
+@pytest.mark.parametrize("name", ["rmi", "nn", "selnet", "linear"])
+def test_stacked_params_cache_refit_misses(world, name):
+    """The stacked parameters are built once per set of leaves: the same
+    leaves hit the cache; a refit's new leaves miss it, are stacked anew
+    and filter as the refit estimator does; a refit that keeps the fn
+    and shapes reuses the compiled program."""
+    R, Q, _ = world
+    est = _fitted_estimator(name, R)
+    predict = est.device_predict_fn()
+    eng = JoinEngine(R, "l2", backend="jnp")
+    prog, stacked = eng._filter_program(predict)
+    again = eng._filter_program(predict)
+    assert again[0] is prog and again[1] is stacked
+    _refit(est, R, seed=1)
+    refit = est.device_predict_fn()
+    prog2, stacked2 = eng._filter_program(refit)
+    assert stacked2 is not stacked
+    assert any(not np.array_equal(np.asarray(a), np.asarray(b))
+               for a, b in zip(stacked, stacked2))
+    if refit[1] is predict[1]:
+        assert prog2 is prog and len(eng._filter_progs) == 1
+    st = eng._stage_filter(Q, 0.8, predict=refit, threshold=3.0)
+    want = _filter_on_leaves(refit, st, 3.0)
+    np.testing.assert_array_equal(np.asarray(st.pos_dev), np.asarray(want[1]))
+    assert eng._filter_program(refit)[1] is stacked2
 
 
 def test_bucket_size_reexport():

@@ -3,7 +3,8 @@
 Covers: the span tree `JoinPlan.run` opens on the filter route, the
 exact route and a batch with no positives; the counts each span carries
 (`rows`, `h2d_bytes`, `n_pos`, `capacity`) against the call's own
-numbers and the query buffer actually uploaded; one `batch` id tying a streamed batch's stage,
+numbers and the query buffer actually uploaded; the filter dispatch's
+`args` and `uploads`, none after a stream's first batch; one `batch` id tying a streamed batch's stage,
 verify and result spans together across the `submit` calls of a depth-2
 session; the declared syncs (`_note_host_sync`) as `join.sync.<kind>`
 spans; a real profiler session on the CPU returning the spans with
@@ -134,6 +135,10 @@ def test_filter_route_span_tree(data, rec, staged):
     assert stage.counts == {"batch": st.batch, "rows": len(Q),
                             "h2d_bytes": st.qdev.nbytes}
     assert st.qdev.shape[0] > len(Q)            # the padding is counted
+    # one stacked parameter buffer + queries, eps, threshold, row count;
+    # a new engine uploads the three scalars
+    (filt,) = rec.named("join.stage.filter")
+    assert filt.counts == {"args": 5, "uploads": 3}
     (verify,) = rec.named("join.verify")
     assert verify.counts["n_pos"] == res.n_searched
     assert verify.counts["capacity"] >= res.n_searched
@@ -196,6 +201,17 @@ def test_session_batches_share_one_id(data, rec):
     assert rec.named("join.flush")[0].parent is None
 
 
+def test_stream_uploads_no_scalars_after_first_batch(data, rec):
+    """A stream's radius, threshold and row count are the same on every
+    batch: the first batch uploads them, later ones reuse them."""
+    R, Q = data
+    plan = _plan(R, FirstCoordFilter(0.0))
+    list(plan.stream([Q[:100], Q[100:200], Q[200:300]], EPS))
+    filt = rec.named("join.stage.filter")
+    assert [s.counts["uploads"] for s in filt] == [3, 0, 0]
+    assert {s.counts["args"] for s in filt} == {5}
+
+
 def test_profiler_session_returns_the_spans(data, tmp_path):
     """Under a real profiler session the spans land in the trace with
     their counts as event stats."""
@@ -220,6 +236,7 @@ def test_profiler_session_returns_the_spans(data, tmp_path):
     assert stage["rows"] == len(Q)
     assert stage["h2d_bytes"] == padded * Q.shape[1] * 4
     assert found["join.verify"][0]["n_pos"] == res.n_searched
+    assert found["join.stage.filter"] == [{"args": 5, "uploads": 0}]
 
 
 def test_program_module_names(data):
@@ -230,9 +247,9 @@ def test_program_module_names(data):
     eng = engine.JoinEngine(R, "cosine", backend="jnp")
     predict = (jnp.float32(0.0), _first_coord)
     st = eng._stage_filter(Q, EPS, predict=predict, threshold=0.0)
-    lowered = eng._filter_program(predict).lower(
-        predict[0], st.qdev, st.eps_dev, jnp.float32(0.0),
-        jnp.int32(st.n))
+    prog, stacked = eng._filter_program(predict)
+    lowered = prog.lower(stacked, st.qdev, st.eps_dev, jnp.float32(0.0),
+                         jnp.int32(st.n))
     assert "module @jit_program" in lowered.as_text()
     cprog = _compact_program(eng.mesh, eng.data_axis, eng.backend,
                              eng.metric, eng.block_q, eng.block_r, eng.nr,
