@@ -383,8 +383,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     route, loop = route_module(traffic["route"]), loop_module(traffic["loop"])
     clock = CompileClock()
     spans: dict = {}
+    t0 = time.perf_counter()
     with jax.profiler.TraceAnnotation("bench.draw"):
         R, Q = draw(cfg, seed, int(traffic["pool"]), int(traffic["rows"]))
+    log(f"draw: {len(R) + len(Q)} rows x {R.shape[1]}, "
+        f"{R.nbytes + Q.nbytes} bytes in {time.perf_counter() - t0:.3f} s")
     pool = Pool(Q, int(traffic["rows"]))
     eps = float(cfg["eps"])
     plan = route.build(cfg, R, seed, spans)
