@@ -692,7 +692,9 @@ class JoinEngine:
     def _upload_R(self, R: np.ndarray) -> None:
         """Pad R to the topology's row quantum and pin it on the mesh —
         shared by `__init__` and `compact()` (which re-uploads the merged
-        logical set after evicting the compiled programs)."""
+        logical set after evicting the compiled programs).  The transfer's
+        dispatch is the span `join.place_r` (`rows` of R, `shards`, and
+        `bytes_per_chip`, the padded bytes each device holds)."""
         self.nr = len(R)
         # host-side R backs lazy approximate-verifier construction (§5);
         # np.asarray is a no-copy view for float32 input
@@ -710,15 +712,24 @@ class JoinEngine:
         self.nr_padded = len(Rp)
         nrv = self.topology.nr_valid_shards(self.nr, self.nr_padded,
                                             self.mesh)
-        if self.mesh is not None:
-            r_sharding = NamedSharding(self.mesh, self.topology.r_spec())
-            self._Rdev = _put_row_major(Rp, r_sharding)
-            self._nrv_dev = None if nrv is None else jax.device_put(
-                nrv, r_sharding)
-        else:
-            self._Rdev = _put_row_major(
-                Rp, SingleDeviceSharding(jax.devices()[0]))
-            self._nrv_dev = None if nrv is None else jnp.asarray(nrv)
+        with _span("join.place_r", rows=self.nr, shards=self.r_shards,
+                   bytes_per_chip=self.per_device_r_bytes):
+            if self.mesh is not None:
+                r_sharding = NamedSharding(self.mesh, self.topology.r_spec())
+                self._Rdev = _put_row_major(Rp, r_sharding)
+                self._nrv_dev = None if nrv is None else jax.device_put(
+                    nrv, r_sharding)
+            else:
+                self._Rdev = _put_row_major(
+                    Rp, SingleDeviceSharding(jax.devices()[0]))
+                self._nrv_dev = None if nrv is None else jnp.asarray(nrv)
+
+    @property
+    def r_device(self) -> jax.Array:
+        """The padded R as it lives on the device(s), sharded by the
+        topology's `r_spec` (read-only: `compact()` replaces it, never
+        writes into it). Callers may wait on it to time R's landing."""
+        return self._Rdev
 
     @property
     def per_device_r_bytes(self) -> int:
@@ -1234,8 +1245,9 @@ class JoinEngine:
 
         Spans: `join.sync.n_pos` around a count read not made in stage 2,
         and `join.verify` (`batch`, `n_pos`, `capacity`: the rows verify
-        runs on) from the count to the end of the dispatch; a batch with
-        no positives verifies nothing and opens none."""
+        runs on, `r_shards`: the R shards each of them is swept against)
+        from the count to the end of the dispatch; a batch with no
+        positives verifies nothing and opens none."""
         label = _check_verify(verify)       # fail fast, not data-dependently
         if st.n_pos is None:                # direct callers skipped stage 2
             with _allowed_transfer("n_pos", batch=st.batch):
@@ -1258,7 +1270,7 @@ class JoinEngine:
         else:
             capacity = n_pos    # the host routes verify the positives as is
         with _span("join.verify", batch=st.batch, n_pos=n_pos,
-                   capacity=capacity):
+                   capacity=capacity, r_shards=self.r_shards):
             finalize = self._dispatch_verify(st, verify, label, capacity)
         return PendingJoin(finalize, verify=label, n_searched=n_pos,
                            batch=st.batch, probe=probe_label)

@@ -8,12 +8,18 @@ numbers and the query buffer actually uploaded; the filter dispatch's
 verify and result spans together across the `submit` calls of a depth-2
 session; the declared syncs (`_note_host_sync`) as `join.sync.<kind>`
 spans; a real profiler session on the CPU returning the spans with
-their counts as event stats; and the module names the trace gives the
-filter and verify programs.
+their counts as event stats; the module names the trace gives the
+filter and verify programs; and R's landing (`join.place_r`) with the
+shards verify sweeps (`r_shards`), on one device and, in a child
+process on four virtual devices, under `topology="ring"`.
 """
 import contextlib
 import dataclasses
 import glob
+import json
+import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -258,3 +264,77 @@ def test_program_module_names(data):
     lowered = cprog.lower(st.qdev, st.pos_dev, st.n_pos_dev, w.Rdev,
                           st.eps_dev, w.nrv, capacity=st.qdev.shape[0])
     assert "module @jit_prog " in lowered.as_text()
+
+
+def test_place_r_span_on_one_device(data, rec):
+    """The engine's construction lands R under `join.place_r`; one
+    device holds all of padded R, and verify sweeps one shard."""
+    R, Q = data
+    plan = _plan(R, "none")
+    eng = plan.engine
+    (place,) = rec.named("join.place_r")
+    assert place.parent is None
+    assert place.counts == {"rows": len(R), "shards": 1,
+                            "bytes_per_chip": eng.per_device_r_bytes}
+    assert eng.per_device_r_bytes == eng.nr_padded * R.shape[1] * 4
+    assert eng.r_device.shape == (eng.nr_padded, R.shape[1])
+    plan.run(Q, EPS)
+    (verify,) = rec.named("join.verify")
+    assert verify.counts["r_shards"] == 1
+
+
+RING_CHILD = """
+import json, os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import numpy as np, jax
+from repro.core import JoinPlan, engine
+spans = []
+class Span:
+    def __init__(self, name, **counts):
+        spans.append([name, counts])
+    def __enter__(self):
+        return self
+    def __exit__(self, *exc):
+        return False
+engine._span = Span
+rng = np.random.default_rng(5)
+def unit(n, d=16):
+    x = rng.normal(size=(n, d)).astype(np.float32)
+    return x / np.linalg.norm(x, axis=1, keepdims=True)
+R, Q = unit(2500), unit(300)
+plan = (JoinPlan(R, "cosine").search("naive")
+        .on(backend="jnp", topology="ring", r_shards=4).filter("none")
+        .build())
+eng = plan.engine
+res = plan.run(Q, 0.45)
+want = JoinPlan(R, "cosine").on(backend="ref").filter("none").run(Q, 0.45)
+print(json.dumps({
+    "spans": spans, "nr_padded": eng.nr_padded,
+    "per_device_r_bytes": eng.per_device_r_bytes,
+    "shard_devices": len({s.device for s in eng.r_device.addressable_shards}),
+    "equal": bool((res.counts == want.counts).all())}))
+"""
+
+
+def test_ring_spans_on_four_devices():
+    """Under `topology="ring", r_shards=4` on four devices, R lands in
+    four shards of a quarter of its padded bytes each, and verify sweeps
+    every query against four shards (a forced four-device child)."""
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", RING_CHILD], env=env,
+                         capture_output=True, text=True, timeout=300,
+                         cwd=os.path.dirname(os.path.dirname(
+                             os.path.abspath(__file__))))
+    assert out.returncode == 0, out.stderr[-3000:]
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    named = {}
+    for name, counts in got["spans"]:
+        named.setdefault(name, []).append(counts)
+    # 2,500 rows padded to 4,096 (512 x 4): 1,024 rows of 16 floats a chip
+    assert got["nr_padded"] == 4096
+    assert got["per_device_r_bytes"] == 1024 * 16 * 4
+    assert got["shard_devices"] == 4 and got["equal"]
+    ring_place = [c for c in named["join.place_r"] if c["shards"] == 4]
+    assert ring_place == [{"rows": 2500, "shards": 4,
+                           "bytes_per_chip": 1024 * 16 * 4}]
+    assert [c["r_shards"] for c in named["join.verify"]] == [4, 1]

@@ -1,6 +1,7 @@
 """Compile rehearsals for a TPU v5e: every Pallas kernel of the main path,
-at the widths the chip runs it, compiled ahead of time against a
-described (not attached) ``v5e:2x2`` topology.
+at the widths the chip runs it, and the exact join's verify with R
+sharded over the four chips, compiled ahead of time against a described
+(not attached) ``v5e:2x2`` topology.
 
 Each test lowers the kernel with `interpret=False`, compiles it with the
 TPU compiler and asserts that the program holds the Mosaic kernel
@@ -16,24 +17,28 @@ time, so nothing touches it while test modules are imported.
 """
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.experimental.layout import Format, Layout
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+from repro.core.topology import RingSharded
 from repro.kernels.adc_rank import adc_rank_pallas
 from repro.kernels.fused_mlp import mlp_forward_pallas
 from repro.kernels.lsh_gather import lsh_bucket_gather_pallas
 from repro.kernels.range_count import range_count_hist_pallas
+from repro.launch.mesh import make_mesh
 from repro.models.mlp import PAPER_WIDTHS
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """A sharding on chip 0 of a described v5e:2x2 host (skips when the
-    TPU compiler cannot describe one).  The persistent compilation cache
-    is off meanwhile: a compile for a described chip cannot be read
-    back."""
+def topo():
+    """A described v5e:2x2 host (skips when the TPU compiler cannot
+    describe one).  The persistent compilation cache is off meanwhile: a
+    compile for a described chip cannot be read back."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
 
@@ -48,9 +53,15 @@ def one_chip():
         jax.config.update("jax_enable_compilation_cache", False)
         cc.reset_cache()
         try:
-            yield SingleDeviceSharding(topo.devices[0])
+            yield topo
         finally:
             jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """A sharding on chip 0 of the described host."""
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _spec(shape, dtype, sharding):
@@ -111,3 +122,36 @@ def test_adc_rank_compiles_for_v5e(one_chip, pool):
         _spec((m, 256, seg), jnp.float32, one_chip),
         _spec((64, pool), jnp.int32, one_chip),
         _spec((n, m), jnp.uint8, one_chip)).compile())
+
+
+def test_ring_verify_compiles_for_v5e_2x2(topo):
+    """The exact join's verify on `topology="ring", r_shards=4`
+    (`RingSharded.compact_program`) at the `glove200-ring4-exact` cell's
+    shapes: R of 1,183,744 x 200 f32, row-major, in four shards, and a
+    bucket of 32,768 compacted queries.  Each chip holds a quarter of R
+    (about 0.3 GB of arguments, not R's 0.95 GB); the compacted block
+    reaches every shard and the counts are summed across chips by
+    all-reduces; only integers move by collective-permute (the
+    positives' prefix sum), no query block rotates as in the ring's
+    sweep."""
+    mesh = make_mesh((4, 1), ("r", "data"), devices=topo.devices)
+    nq, nr, d = 32768, 1183744, 200
+    q_shard = NamedSharding(mesh, P(("r", "data")))
+    r_shard = NamedSharding(mesh, P("r"))
+    rep = NamedSharding(mesh, P())
+    prog = RingSharded().compact_program(mesh, "data", "pallas", "cosine",
+                                         256, 512, 1183514)
+    compiled = prog.lower(
+        _spec((nq, d), jnp.float32, q_shard),
+        _spec((nq,), jnp.bool_, q_shard),
+        _spec((), jnp.int32, rep),
+        _spec((nr, d), jnp.float32, Format(Layout((0, 1)), r_shard)),
+        _spec((), jnp.float32, rep),
+        _spec((4,), jnp.int32, r_shard), capacity=nq).compile()
+    assert compiled.memory_analysis().argument_size_in_bytes < 0.4e9
+    hlo = compiled.as_text()
+    assert re.search(r"= f32\[32768,200\][^ ]* all-reduce\(", hlo)
+    assert re.search(r"= s32\[32768,1\][^ ]* all-reduce\(", hlo)
+    permuted = re.findall(r"= \((\w+)\[.*? collective-permute-start\(",
+                          hlo)
+    assert set(permuted) <= {"s32"}
